@@ -244,7 +244,7 @@ def run(csv_rows, payload=None):
     ref_attn = jax.jit(lambda a, b, c: ref.flash_attention_ref(a, b, c))
     us = _time(ref_attn, q, k, v)
     csv_rows.append(f"kernel_attention_ref_jnp,{us:.0f},B1H8S512D64")
-    out_p = flash_attention(q, k, v, bq=128, bk=128)
+    out_p = flash_attention(q, k, v, bq=128, bk=128, interpret=True)
     err = float(jnp.max(jnp.abs(out_p - ref.flash_attention_ref(q, k, v))))
     csv_rows.append(f"kernel_attention_pallas_interp,0,max_err={err:.2e}")
 
@@ -252,7 +252,7 @@ def run(csv_rows, payload=None):
     rhs = jax.random.normal(ks[1], (8, 512, 256), jnp.float32)
     us = _time(jax.jit(ref.grouped_matmul_ref), lhs, rhs)
     csv_rows.append(f"kernel_gmm_ref_jnp,{us:.0f},E8C256K512F256")
-    out_g = grouped_matmul(lhs, rhs, bc=128, bf=128, bk=256)
+    out_g = grouped_matmul(lhs, rhs, bc=128, bf=128, bk=256, interpret=True)
     err = float(jnp.max(jnp.abs(out_g - ref.grouped_matmul_ref(lhs, rhs))))
     csv_rows.append(f"kernel_gmm_pallas_interp,0,max_err={err:.2e}")
 
@@ -261,7 +261,7 @@ def run(csv_rows, payload=None):
     zp = jax.random.uniform(ks[2], (1024, 1), jnp.float32, -1, 1)
     us = _time(jax.jit(lambda a, b, c: ref.int4_dequant_ref(a, b, c)), pk, sc, zp)
     csv_rows.append(f"kernel_dequant_ref_jnp,{us:.0f},G1024gs128")
-    out_d = int4_dequant(pk, sc, zp)
+    out_d = int4_dequant(pk, sc, zp, interpret=True)
     err = float(
         jnp.max(
             jnp.abs(

@@ -5,15 +5,15 @@ logit-softcap support, GQA-aware (kv head = q head // group).
 
 TPU mapping: grid (B, Hq, Sq/bq, Sk/bk) with the kv axis innermost and
 sequential (carry in VMEM scratch); q/k/v tiles live in VMEM via BlockSpec,
-MXU-aligned tile sizes (bq, bk multiples of 128 on real hardware; tests use
-smaller interpret-mode tiles). Scratch: f32 accumulator (bq, hd) + running
+tile sizes from ``tile_size`` (aligned, or the whole sequence; ragged
+lengths are padded and the padded keys masked). Scratch: f32 accumulator (bq, hd) + running
 max/sum (bq,) — the standard FlashAttention-2 recurrence.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -23,18 +23,39 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -2.0e38
 
 
-def tile_size(n: int, pref: int) -> int:
-    """Largest divisor of ``n`` that is <= ``pref``.
+SUBLANE = 8  # second-minor block dims: multiples of this, or the full dim
+LANE = 128  # minor block dims: multiples of this, or the full dim
 
-    Tile shapes must divide the operand (the BlockSpec grids here carry
-    no masking); preferring 128 keeps real-TPU tiles MXU-aligned while
-    odd interpret-mode shapes (prompt buckets, capacity slabs, per-shard
-    head counts) degrade to a smaller exact tile instead of asserting.
+
+def tile_size(n: int, pref: int, align: int) -> Tuple[int, int]:
+    """``(tile, padded_n)`` for a dimension of size ``n``.
+
+    The TPU lowering accepts a block dim that is a multiple of ``align``
+    (``SUBLANE`` or ``LANE``, by the dim's place in its block) or equal
+    to the whole dimension. A dim no larger than ``pref`` is taken whole;
+    otherwise the tile is the largest multiple of ``align`` up to
+    ``pref`` that divides ``n``. Where none does, ``n`` is padded up to a
+    multiple of ``align`` and tiled the same way — the caller pads the
+    operand and slices the result, never falling back to an unaligned
+    tile.
     """
-    t = max(1, min(pref, n))
-    while n % t:
-        t -= 1
-    return t
+    if n <= pref:
+        return n, n
+    padded = -(-n // align) * align
+    t = max(align, pref - pref % align)
+    while padded % t:
+        t -= align
+    return t, padded
+
+
+def pad_dim(x: jax.Array, axis: int, size: int) -> jax.Array:
+    """Zero-pad ``x`` along ``axis`` up to ``size``."""
+    extra = size - x.shape[axis]
+    if extra == 0:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, extra)
+    return jnp.pad(x, widths)
 
 
 def _flash_kernel(
@@ -54,6 +75,7 @@ def _flash_kernel(
     bk: int,
     n_kv: int,
     q_offset: int,
+    kv_len: int,
 ):
     i = pl.program_id(2)  # q block
     j = pl.program_id(3)  # kv block (sequential, innermost)
@@ -75,9 +97,9 @@ def _flash_kernel(
     # queries align to the END of the kv sequence when Sq != Sk
     qpos = q_offset + i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     kpos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    ok = jnp.ones((bq, bk), bool)
+    ok = kpos < kv_len  # padded keys never score
     if causal:
-        ok = kpos <= qpos
+        ok &= kpos <= qpos
         if window > 0:
             ok &= (qpos - kpos) < window
     s = jnp.where(ok, s, NEG_INF)
@@ -113,18 +135,24 @@ def flash_attention(
     scale: Optional[float] = None,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
-    """q: (B, Hq, Sq, hd); k/v: (B, Hkv, Sk, hd) -> (B, Hq, Sq, hd)."""
+    """q: (B, Hq, Sq, hd); k/v: (B, Hkv, Sk, hd) -> (B, Hq, Sq, hd).
+
+    ``interpret`` has no default: only a caller off the TPU asks for the
+    Pallas interpreter."""
     B, Hq, Sq, hd = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
     G = Hq // Hkv
     if scale is None:
         scale = hd**-0.5
-    bq = tile_size(Sq, bq)
-    bk = tile_size(Sk, bk)
-    n_kv = Sk // bk
-    grid = (B, Hq, Sq // bq, n_kv)
+    bq, sq_p = tile_size(Sq, bq, SUBLANE)
+    bk, sk_p = tile_size(Sk, bk, SUBLANE)
+    q = pad_dim(q, 2, sq_p)
+    k = pad_dim(k, 2, sk_p)
+    v = pad_dim(v, 2, sk_p)
+    n_kv = sk_p // bk
+    grid = (B, Hq, sq_p // bq, n_kv)
 
     kernel = functools.partial(
         _flash_kernel,
@@ -136,9 +164,10 @@ def flash_attention(
         bk=bk,
         n_kv=n_kv,
         q_offset=Sk - Sq,
+        kv_len=Sk,
     )
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -147,7 +176,7 @@ def flash_attention(
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, h // G, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, sq_p, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, hd), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
@@ -155,3 +184,4 @@ def flash_attention(
         ],
         interpret=interpret,
     )(q, k, v)
+    return out[:, :, :Sq]

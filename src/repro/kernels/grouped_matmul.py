@@ -6,8 +6,8 @@ EP path (post-all_to_all slabs) and the TP path (f sharded) of
 ``repro.models.moe``.
 
 TPU mapping: grid (E, C/bc, f/bf, d/bk) with the contraction axis
-innermost/sequential; f32 VMEM accumulator scratch; tiles MXU-aligned
-(128x128 on hardware). VMEM working set per step:
+innermost/sequential; f32 VMEM accumulator scratch; tiles aligned to the
+TPU's (8, 128) tiling or whole dims (``tile_size``). VMEM working set per step:
 bc*bk + bk*bf + bc*bf floats — e.g. 128^2 * 3 * 4B = 192 KiB.
 """
 
@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import tile_size
+from .flash_attention import LANE, SUBLANE, pad_dim, tile_size
 
 
 def _gmm_kernel(lhs_ref, rhs_ref, out_ref, acc_ref, *, n_k: int):
@@ -49,21 +49,27 @@ def grouped_matmul(
     bc: int = 128,
     bf: int = 128,
     bk: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
-    """(E, C, d) x (E, d, f) -> (E, C, f) with f32 accumulation."""
+    """(E, C, d) x (E, d, f) -> (E, C, f) with f32 accumulation.
+
+    ``interpret`` has no default: only a caller off the TPU asks for the
+    Pallas interpreter."""
     E, C, d = lhs.shape
     f = rhs.shape[2]
     assert rhs.shape[:2] == (E, d)
-    # exact-divisor tiles: per-plan shapes (capacity slabs, d_ff shards)
-    # degrade to smaller tiles instead of asserting (see tile_size)
-    bc = tile_size(C, bc)
-    bf = tile_size(f, bf)
-    bk = tile_size(d, bk)
-    n_k = d // bk
-    grid = (E, C // bc, f // bf, n_k)
+    # aligned tiles (or whole dims); ragged per-plan shapes (capacity
+    # slabs, d_ff shards) are zero-padded — zero rows and zero
+    # contraction terms leave the product unchanged (see tile_size)
+    bc, c_p = tile_size(C, bc, SUBLANE)
+    bf, f_p = tile_size(f, bf, LANE)
+    bk, d_p = tile_size(d, bk, LANE)
+    lhs = pad_dim(pad_dim(lhs, 1, c_p), 2, d_p)
+    rhs = pad_dim(pad_dim(rhs, 1, d_p), 2, f_p)
+    n_k = d_p // bk
+    grid = (E, c_p // bc, f_p // bf, n_k)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_gmm_kernel, n_k=n_k),
         grid=grid,
         in_specs=[
@@ -71,7 +77,8 @@ def grouped_matmul(
             pl.BlockSpec((1, bk, bf), lambda e, i, j, kk: (e, kk, j)),
         ],
         out_specs=pl.BlockSpec((1, bc, bf), lambda e, i, j, kk: (e, i, j)),
-        out_shape=jax.ShapeDtypeStruct((E, C, f), lhs.dtype),
+        out_shape=jax.ShapeDtypeStruct((E, c_p, f_p), lhs.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
         interpret=interpret,
     )(lhs, rhs)
+    return out[:, :C, :f]

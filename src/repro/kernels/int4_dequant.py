@@ -24,9 +24,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .flash_attention import SUBLANE, pad_dim, tile_size
+
 
 def _dequant_kernel(packed_ref, scale_ref, zero_ref, out_ref):
-    packed = packed_ref[...]
+    # widen before the bit twiddling: the TPU has no uint8 -> f32 cast
+    packed = packed_ref[...].astype(jnp.int32)
     low = (packed & 0xF).astype(jnp.float32)
     high = (packed >> 4).astype(jnp.float32)
     bg, half = packed.shape
@@ -43,16 +46,19 @@ def int4_dequant(
     *,
     out_dtype=jnp.bfloat16,
     bg: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
-    """packed (G, gs/2) uint8 + scales/zeros (G, 1) -> (G, gs) out_dtype."""
+    """packed (G, gs/2) uint8 + scales/zeros (G, 1) -> (G, gs) out_dtype.
+
+    ``interpret`` has no default: only a caller off the TPU asks for the
+    Pallas interpreter."""
     G, half = packed.shape
     gs = 2 * half
-    bg = min(bg, G)
-    assert G % bg == 0
-    grid = (G // bg,)
+    bg, g_p = tile_size(G, bg, SUBLANE)
+    packed, scales, zeros = (pad_dim(a, 0, g_p) for a in (packed, scales, zeros))
+    grid = (g_p // bg,)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _dequant_kernel,
         grid=grid,
         in_specs=[
@@ -61,6 +67,7 @@ def int4_dequant(
             pl.BlockSpec((bg, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bg, gs), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((G, gs), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((g_p, gs), out_dtype),
         interpret=interpret,
     )(packed, scales, zeros)
+    return out[:G]

@@ -44,7 +44,6 @@ import collections
 import dataclasses
 import enum
 import functools
-import math
 import os
 from typing import Callable, Optional, Tuple, Union
 
@@ -52,11 +51,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding.specs import (
-    SHARD_MAP_KW as _SHARD_MAP_KW,
-    KernelShardAxes,
-    shard_map as _shard_map,
-)
+from repro.sharding.specs import KernelShardAxes
 
 from . import ref
 from .flash_attention import flash_attention as _flash_pallas
@@ -102,11 +97,7 @@ def reset_dispatch_counts() -> None:
 
 def default_backend() -> KernelBackend:
     """The sane backend for the current ``jax.default_backend()``."""
-    try:
-        platform = jax.default_backend()
-    except Exception:
-        platform = "cpu"
-    return _PLATFORM_DEFAULTS.get(platform, KernelBackend.REF)
+    return _PLATFORM_DEFAULTS.get(jax.default_backend(), KernelBackend.REF)
 
 
 def resolve_backend(backend: Union[KernelBackend, str, None] = None) -> KernelBackend:
@@ -117,11 +108,9 @@ def resolve_backend(backend: Union[KernelBackend, str, None] = None) -> KernelBa
 
 
 def interpret_mode() -> bool:
-    """Pallas interpret mode everywhere but on a real TPU backend."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:
-        return True
+    """Pallas interpret mode everywhere but on a real TPU backend — the
+    one place that decides it; the kernels take no default."""
+    return jax.default_backend() != "tpu"
 
 
 def attention(
@@ -223,12 +212,12 @@ def flash_attention(
         return local_call(q, k, v, is_global)
     _record("flash.pallas_shard_map")
     heads = P(None, None, shard_axes.axis, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         local_call,
         mesh=shard_axes.mesh,
         in_specs=(heads, heads, heads, P()),
         out_specs=heads,
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     return fn(q, k, v, jnp.asarray(is_global))
 
@@ -375,7 +364,7 @@ def decode_attention(
 
             # same layout as the unshared map below; the grouping operand
             # is replicated like the tables and write positions
-            fn = _shard_map(
+            fn = jax.shard_map(
                 local_prefix_step,
                 mesh=shard_axes.mesh,
                 in_specs=(
@@ -390,7 +379,7 @@ def decode_attention(
                     P(),
                 ),
                 out_specs=(heads, heads, heads),
-                **_SHARD_MAP_KW,
+                check_vma=False,
             )
             return fn(
                 q,
@@ -426,12 +415,12 @@ def decode_attention(
         # dims stay replicated inside the map — attention is fully
         # head-parallel, so no collective is needed and out_specs just
         # reassemble the head shards.
-        fn = _shard_map(
+        fn = jax.shard_map(
             local_step,
             mesh=shard_axes.mesh,
             in_specs=(heads, heads, heads, P(None, None), heads, heads, P(None), P()),
             out_specs=(heads, heads, heads),
-            **_SHARD_MAP_KW,
+            check_vma=False,
         )
         return fn(
             q, k_cache, v_cache, tables, k_new, v_new, posv, jnp.asarray(is_global)
@@ -602,14 +591,8 @@ def _dequant_weight(rhs, be: KernelBackend, out_dtype) -> jax.Array:
     else:
         return rhs
     if be is KernelBackend.PALLAS:
-        g = packed.shape[0]
         w = _dequant_pallas(
-            packed,
-            scales,
-            zeros,
-            out_dtype=out_dtype,
-            bg=math.gcd(g, 256),
-            interpret=interpret_mode(),
+            packed, scales, zeros, out_dtype=out_dtype, interpret=interpret_mode()
         )
     else:
         w = ref.int4_dequant_ref(packed, scales, zeros, out_dtype=out_dtype)
@@ -693,12 +676,12 @@ def grouped_matmul(
 
     else:
         raise ValueError(f"sharded_dim must be 'out'|'in', got {sharded_dim!r}")
-    fn = _shard_map(
+    fn = jax.shard_map(
         local,
         mesh=shard_axes.mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     return fn(lhs, rhs)
 

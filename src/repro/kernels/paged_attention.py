@@ -7,8 +7,11 @@ softmax, in a single kernel — the pure-jnp path materializes every row's
 gathered ``(B, max_blocks * block_size, Hkv, hd)`` view in HBM per step,
 which this kernel never does.
 
-TPU mapping: grid ``(B, Hkv, max_blocks)`` with the page axis innermost
-and sequential (FlashAttention-2 carry in VMEM scratch). The per-row
+TPU mapping: grid ``(B, max_blocks)`` with the page axis innermost and
+sequential (FlashAttention-2 carry in VMEM scratch). Blocks are whole
+pages ``(1, block_size, Hkv, hd)`` and whole rows ``(1, C, Hq, hd)``, so
+the two minor block dims always equal the array's (the TPU tiling rule
+for blocks); the kernel loops over the kv heads of a page. The per-row
 block-table walk rides the BlockSpec index maps: ``block_tables`` and
 ``pos`` are scalar-prefetch operands (SMEM), so each grid step DMAs
 exactly the physical page ``block_tables[b, j]`` into VMEM — pages are
@@ -36,17 +39,15 @@ Semantics match ``repro.kernels.ref.paged_attention_ref`` exactly:
 GQA: q heads are grouped over kv heads (head ``h`` serves q heads
 ``h*G .. (h+1)*G - 1``); the non-dividing TP head-replication case is
 routed to the reference path by ``repro.kernels.ops``. Heads-sharded
-plans call this kernel *per KV shard* inside a ``shard_map`` (the grid's
-``Hkv`` axis then counts local heads; G is preserved because q and kv
-heads divide the TP axis together — ``ops.decode_attention``). On real
-hardware ``block_size`` should be a multiple of the dtype sublane tile
-and ``head_dim`` a multiple of 128; interpret-mode tests use smaller
-tiles.
+plans call this kernel *per KV shard* inside a ``shard_map`` (the head
+loop then counts local heads; G is preserved because q and kv heads
+divide the TP axis together — ``ops.decode_attention``).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -55,6 +56,136 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.0e38  # finite f32 mask value (see module docstring)
+
+# Mosaic's default scoped-VMEM budget is 16 MiB; a 512-token prefill
+# chunk at 16 heads x 128 needs more (double-buffered q/out/new-KV rows
+# plus the f32 accumulator), so each call asks for what it needs, capped
+# well inside the 128 MiB of a v5e core.
+_VMEM_FLOOR = 16 * 2**20
+_VMEM_CAP = 96 * 2**20
+_ROWS_PER_TILE = 128  # chunk rows per step of the in-kernel query loop
+
+
+def _vmem_params(block_bytes: int, scratch_bytes: int) -> pltpu.CompilerParams:
+    need = 2 * block_bytes + scratch_bytes  # pipelined blocks are 2-deep
+    if need > _VMEM_CAP:
+        raise ValueError(
+            f"paged attention needs ~{need / 2**20:.0f} MiB of VMEM "
+            f"(cap {_VMEM_CAP / 2**20:.0f} MiB): use a smaller prefill chunk"
+        )
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=max(_VMEM_FLOOR, need + need // 2)
+    )
+
+
+def _append_attend_page(
+    q_ref,
+    k_page_ref,
+    v_page_ref,
+    k_new_ref,
+    v_new_ref,
+    k_out_ref,
+    v_out_ref,
+    acc_ref,
+    m_ref,
+    l_ref,
+    lead: tuple,
+    *,
+    p0,
+    j,
+    is_global,
+    scale: float,
+    softcap: float,
+    window: int,
+    bs: int,
+    C: int,
+    tq: int,
+    G: int,
+    Hkv: int,
+):
+    """One page of one row: fused chunk append, then the online-softmax
+    update of every q head, ``tq`` chunk rows at a time (a loop, not an
+    unrolled body: Mosaic's compile time grows steeply with the rows a
+    body handles). ``lead`` prefixes the scratch index (the prefix kernel
+    keeps per-row carries)."""
+    n_tiles = C // tq
+    hd = k_page_ref.shape[-1]
+    # masks are built 2-D from iotas: Mosaic cannot relayout a 1-D bool
+    # vector into a column
+    idx = j * bs - p0 + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
+    wmask = (idx >= 0) & (idx < C)  # (bs, 1): page slot takes a chunk token
+
+    def appended(new_ref, h):
+        """The chunk rows that land in this page's slots, (bs, hd)."""
+        if C == 1:
+            return new_ref[0, :, h, :].astype(jnp.float32)  # broadcasts
+
+        # slot-side one-hot select of the chunk token that lands in each
+        # slot — an MXU matmul instead of an in-kernel gather
+        def tile(t, acc):
+            rows = new_ref[0, pl.ds(t * tq, tq), h, :].astype(jnp.float32)
+            sel = idx - t * tq == jax.lax.broadcasted_iota(jnp.int32, (bs, tq), 1)
+            return acc + jnp.dot(
+                sel.astype(jnp.float32), rows, preferred_element_type=jnp.float32
+            )
+
+        return jax.lax.fori_loop(0, n_tiles, tile, jnp.zeros((bs, hd), jnp.float32))
+
+    def attend(qh, k_page, v_page):
+        def tile(t, carry):
+            q = q_ref[0, pl.ds(t * tq, tq), qh, :].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k_page, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # (tq, bs)
+            if softcap > 0:
+                s = softcap * jnp.tanh(s / softcap)
+            kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (tq, bs), 1)
+            qpos = p0 + t * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, bs), 0)
+            ok = kpos <= qpos  # causal — also kills stale slots
+            if window > 0:
+                ok = ok & (((qpos - kpos) < window) | is_global)
+            s = jnp.where(ok, s, NEG_INF)
+
+            at = lead + (qh, t)
+            m_prev = m_ref[at]  # (tq, 1)
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_cur)
+            p = jnp.exp(s - m_cur)
+            l_ref[at] = l_ref[at] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[at] = acc_ref[at] * alpha + jnp.dot(
+                p, v_page, preferred_element_type=jnp.float32
+            )
+            m_ref[at] = m_cur
+            return carry
+
+        jax.lax.fori_loop(0, n_tiles, tile, 0)
+
+    for h in range(Hkv):
+        k_page = k_page_ref[0, :, h, :].astype(jnp.float32)  # (bs, hd)
+        v_page = v_page_ref[0, :, h, :].astype(jnp.float32)
+        k_page = jnp.where(wmask, appended(k_new_ref, h), k_page)
+        v_page = jnp.where(wmask, appended(v_new_ref, h), v_page)
+        # unconditional write-back: the aliased out buffer holds a
+        # *different* page from the previous grid step, so copying through
+        # is load-bearing
+        k_out_ref[0, :, h, :] = k_page.astype(k_out_ref.dtype)
+        v_out_ref[0, :, h, :] = v_page.astype(v_out_ref.dtype)
+        for g in range(G):
+            attend(h * G + g, k_page, v_page)
+
+
+def _write_out(o_ref, acc_ref, l_ref, lead: tuple, tq: int):
+    C, Hq = o_ref.shape[1], o_ref.shape[2]
+    for qh in range(Hq):
+
+        def tile(t, carry, qh=qh):
+            at = lead + (qh, t)
+            out = acc_ref[at] / jnp.maximum(l_ref[at], 1e-30)
+            o_ref[0, pl.ds(t * tq, tq), qh, :] = out.astype(o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, C // tq, tile, 0)
 
 
 def _paged_kernel(
@@ -73,16 +204,11 @@ def _paged_kernel(
     m_ref,
     l_ref,
     *,
-    scale: float,
-    softcap: float,
-    window: int,
-    bs: int,
-    C: int,
-    G: int,
     n_blocks: int,
+    **static,
 ):
     b = pl.program_id(0)
-    j = pl.program_id(2)  # page walk: innermost, sequential
+    j = pl.program_id(1)  # page walk: innermost, sequential
 
     @pl.when(j == 0)
     def _init():
@@ -90,55 +216,15 @@ def _paged_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    p0 = pos_ref[b]
-    kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bs,), 0)
-
-    # fused chunk append: slot-side one-hot select of the chunk token that
-    # lands here (if any) — an MXU matmul instead of an in-kernel gather
-    idx = kpos - p0  # chunk-token index per page slot
-    wmask = (idx >= 0) & (idx < C)
-    sel = idx[:, None] == jax.lax.broadcasted_iota(jnp.int32, (bs, C), 1)
-    sel = (sel & wmask[:, None]).astype(jnp.float32)  # (bs, C)
-
-    k_page = k_page_ref[0, :, 0, :].astype(jnp.float32)  # (bs, hd)
-    v_page = v_page_ref[0, :, 0, :].astype(jnp.float32)
-    k_new = k_new_ref[0, :, 0, :].astype(jnp.float32)  # (C, hd)
-    v_new = v_new_ref[0, :, 0, :].astype(jnp.float32)
-    k_page = jnp.where(wmask[:, None], jnp.dot(sel, k_new), k_page)
-    v_page = jnp.where(wmask[:, None], jnp.dot(sel, v_new), v_page)
-    # unconditional write-back: the aliased out buffer holds a *different*
-    # page from the previous grid step, so copying through is load-bearing
-    k_out_ref[0, :, 0, :] = k_page.astype(k_out_ref.dtype)
-    v_out_ref[0, :, 0, :] = v_page.astype(v_out_ref.dtype)
-
-    q = q_ref[0, :, :, :].astype(jnp.float32).reshape(C * G, -1)
-    s = jnp.dot(q, k_page.T, preferred_element_type=jnp.float32) * scale
-    if softcap > 0:
-        s = softcap * jnp.tanh(s / softcap)
-
-    qpos = p0 + jax.lax.broadcasted_iota(jnp.int32, (C, G), 0).reshape(C * G)
-    ok = kpos[None, :] <= qpos[:, None]  # causal — also kills stale slots
-    if window > 0:
-        win = ok & ((qpos[:, None] - kpos[None, :]) < window)
-        ok = jnp.where(flags_ref[0] != 0, ok, win)
-    s = jnp.where(ok, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, None])
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
-        p, v_page, preferred_element_type=jnp.float32
+    _append_attend_page(
+        q_ref, k_page_ref, v_page_ref, k_new_ref, v_new_ref, k_out_ref,
+        v_out_ref, acc_ref, m_ref, l_ref, (),
+        p0=pos_ref[b], j=j, is_global=flags_ref[0] != 0, **static,
     )
-    m_ref[...] = m_cur
 
     @pl.when(j == n_blocks - 1)
     def _finalize():
-        lse = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, :, :] = (acc_ref[...] / lse[:, None]).reshape(C, G, -1).astype(
-            o_ref.dtype
-        )
+        _write_out(o_ref, acc_ref, l_ref, (), static["tq"])
 
 
 def _prefix_kernel(
@@ -159,23 +245,19 @@ def _prefix_kernel(
     m_ref,
     l_ref,
     *,
-    scale: float,
-    softcap: float,
-    window: int,
-    bs: int,
-    C: int,
-    G: int,
     n_blocks: int,
+    **static,
 ):
-    """Prefix-group variant of ``_paged_kernel``: grid (Hkv, n_blocks, B)
-    with the *row* axis innermost, so consecutive rows of one prefix
-    group hit the same physical page at a shared ``j`` — the page BlockSpec
-    resolves to the group representative's table entry there, and Pallas's
-    revisit elision skips the re-DMA (the shared block is walked once per
-    group, not once per row). Per-row online-softmax carries live in
-    row-indexed VMEM scratch since the row axis is no longer outermost."""
-    j = pl.program_id(1)  # page walk: sequential, but no longer innermost
-    b = pl.program_id(2)
+    """Prefix-group variant of ``_paged_kernel``: grid (n_blocks, B) with
+    the *row* axis innermost, so consecutive rows of one prefix group hit
+    the same physical page at a shared ``j`` — the page BlockSpec
+    resolves to the group representative's table entry there, and
+    Pallas's revisit elision skips the re-DMA (the shared block is walked
+    once per group, not once per row). Per-row online-softmax carries
+    live in row-indexed VMEM scratch since the row axis is no longer
+    outermost."""
+    j = pl.program_id(0)  # page walk: sequential, but no longer innermost
+    b = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -183,54 +265,80 @@ def _prefix_kernel(
         m_ref[b] = jnp.full_like(m_ref[b], NEG_INF)
         l_ref[b] = jnp.zeros_like(l_ref[b])
 
-    p0 = pos_ref[b]
-    kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bs,), 0)
-
-    # fused chunk append — writes only ever land in exclusively-owned
-    # pages (pos[b] >= shared_blocks[b] * bs: COW ran before the step),
-    # so shared pages always copy through unchanged below
-    idx = kpos - p0
-    wmask = (idx >= 0) & (idx < C)
-    sel = idx[:, None] == jax.lax.broadcasted_iota(jnp.int32, (bs, C), 1)
-    sel = (sel & wmask[:, None]).astype(jnp.float32)  # (bs, C)
-
-    k_page = k_page_ref[0, :, 0, :].astype(jnp.float32)  # (bs, hd)
-    v_page = v_page_ref[0, :, 0, :].astype(jnp.float32)
-    k_new = k_new_ref[0, :, 0, :].astype(jnp.float32)  # (C, hd)
-    v_new = v_new_ref[0, :, 0, :].astype(jnp.float32)
-    k_page = jnp.where(wmask[:, None], jnp.dot(sel, k_new), k_page)
-    v_page = jnp.where(wmask[:, None], jnp.dot(sel, v_new), v_page)
-    k_out_ref[0, :, 0, :] = k_page.astype(k_out_ref.dtype)
-    v_out_ref[0, :, 0, :] = v_page.astype(v_out_ref.dtype)
-
-    q = q_ref[0, :, :, :].astype(jnp.float32).reshape(C * G, -1)
-    s = jnp.dot(q, k_page.T, preferred_element_type=jnp.float32) * scale
-    if softcap > 0:
-        s = softcap * jnp.tanh(s / softcap)
-
-    qpos = p0 + jax.lax.broadcasted_iota(jnp.int32, (C, G), 0).reshape(C * G)
-    ok = kpos[None, :] <= qpos[:, None]  # causal — also kills stale slots
-    if window > 0:
-        win = ok & ((qpos[:, None] - kpos[None, :]) < window)
-        ok = jnp.where(flags_ref[0] != 0, ok, win)
-    s = jnp.where(ok, s, NEG_INF)
-
-    m_prev = m_ref[b]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, None])
-    l_ref[b] = l_ref[b] * alpha + jnp.sum(p, axis=-1)
-    acc_ref[b] = acc_ref[b] * alpha[:, None] + jnp.dot(
-        p, v_page, preferred_element_type=jnp.float32
+    # writes only ever land in exclusively-owned pages (pos[b] >=
+    # shared_blocks[b] * bs: COW ran before the step), so shared pages
+    # always copy through unchanged
+    _append_attend_page(
+        q_ref, k_page_ref, v_page_ref, k_new_ref, v_new_ref, k_out_ref,
+        v_out_ref, acc_ref, m_ref, l_ref, (b,),
+        p0=pos_ref[b], j=j, is_global=flags_ref[0] != 0, **static,
     )
-    m_ref[b] = m_cur
 
     @pl.when(j == n_blocks - 1)
     def _finalize():
-        lse = jnp.maximum(l_ref[b], 1e-30)
-        o_ref[0, :, :, :] = (acc_ref[b] / lse[:, None]).reshape(C, G, -1).astype(
-            o_ref.dtype
-        )
+        _write_out(o_ref, acc_ref, l_ref, (b,), static["tq"])
+
+
+def _paged_call(kernel, index_maps, n_prefetch, grid, n_blocks, lead, q,
+                k_pages, v_pages, k_new, v_new, scale, softcap, window,
+                interpret):
+    """Shared ``pallas_call`` plumbing: whole-page / whole-row blocks,
+    per-q-head f32 carries (``lead`` extra leading scratch dims), pages
+    aliased to the page outputs."""
+    B, C, Hq, hd = q.shape
+    bs, Hkv = k_pages.shape[1], k_pages.shape[2]
+    if Hq % Hkv:
+        raise ValueError("GQA requires q heads to divide over kv heads")
+    page_map, row_map = index_maps
+    page_spec = pl.BlockSpec((1, bs, Hkv, hd), page_map)
+    q_spec = pl.BlockSpec((1, C, Hq, hd), row_map)
+    kv_spec = pl.BlockSpec((1, C, Hkv, hd), row_map)
+    tq = max(t for t in range(1, min(C, _ROWS_PER_TILE) + 1) if C % t == 0)
+    carry = lead + (Hq, C // tq, tq)
+    scratch = [
+        pltpu.VMEM(carry + (hd,), jnp.float32),
+        pltpu.VMEM(carry + (1,), jnp.float32),
+        pltpu.VMEM(carry + (1,), jnp.float32),
+    ]
+    block_bytes = (
+        2 * C * Hq * hd * q.dtype.itemsize  # q in, out
+        + 2 * C * Hkv * hd * k_new.dtype.itemsize  # new K/V rows
+        + 4 * bs * Hkv * hd * k_pages.dtype.itemsize  # pages in + out
+    )
+    # every (tq, hd) / (tq, 1) carry occupies whole (8, 128) f32 tiles
+    scratch_bytes = 4 * math.prod(carry[:-1]) * -(-tq // 8) * 8 * (hd + 2 * 128)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_prefetch,
+        grid=grid,
+        in_specs=[q_spec, page_spec, page_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, page_spec, page_spec],
+        scratch_shapes=scratch,
+    )
+    kernel = functools.partial(
+        kernel,
+        scale=hd**-0.5 if scale is None else scale,
+        softcap=softcap,
+        window=window,
+        bs=bs,
+        C=C,
+        tq=tq,
+        G=Hq // Hkv,
+        Hkv=Hkv,
+        n_blocks=n_blocks,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+            jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
+        ],
+        # operand indices count the scalar-prefetch args: pages -> page outs
+        input_output_aliases={n_prefetch + 1: 1, n_prefetch + 2: 2},
+        compiler_params=_vmem_params(block_bytes, scratch_bytes),
+        interpret=interpret,
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "softcap", "window", "interpret"))
@@ -249,7 +357,7 @@ def prefix_paged_attention(
     scale: Optional[float] = None,
     softcap: float = 0.0,
     window: int = 0,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Prefix-group fused paged append + decode attention.
 
@@ -262,63 +370,29 @@ def prefix_paged_attention(
     revisits the rep's physical page at shared ``j`` and the page DMA is
     elided after the first row. Token-exact vs ``paged_attention`` on the
     rows' own tables (``ref.prefix_paged_attention_ref`` is the oracle).
+    The per-row carries are ``B`` times those of ``paged_attention``: this
+    kernel serves decode steps, not long prefill chunks.
     """
-    B, C, Hq, hd = q.shape
-    bs, Hkv = k_pages.shape[1], k_pages.shape[2]
-    G = Hq // Hkv
-    assert Hq % Hkv == 0, "GQA requires q heads to divide over kv heads"
-    assert pos.shape == (B,), "pos must be a (B,) vector (broadcast scalars)"
-    assert group_reps.shape == (B,) and shared_blocks.shape == (B,)
+    B = q.shape[0]
+    if pos.shape != (B,):
+        raise ValueError("pos must be a (B,) vector (broadcast scalars)")
+    if group_reps.shape != (B,) or shared_blocks.shape != (B,):
+        raise ValueError("group_reps / shared_blocks must be (B,) vectors")
     n_blocks = block_tables.shape[1]
-    if scale is None:
-        scale = hd**-0.5
     flags = jnp.asarray(is_global, jnp.int32).reshape(1)
 
-    kernel = functools.partial(
-        _prefix_kernel,
-        scale=scale,
-        softcap=softcap,
-        window=window,
-        bs=bs,
-        C=C,
-        G=G,
-        n_blocks=n_blocks,
-    )
-
-    def page_idx(h, j, b, tables, pos, flags, reps, nsh):
+    def page_map(j, b, tables, pos, flags, reps, nsh):
         row = jnp.where(j < nsh[b], reps[b], b)
-        return (tables[row, j], 0, h, 0)
+        return (tables[row, j], 0, 0, 0)
 
-    page_spec = pl.BlockSpec((1, bs, 1, hd), page_idx)
-    row_spec = pl.BlockSpec(
-        (1, C, 1, hd), lambda h, j, b, tables, pos, flags, reps, nsh: (b, 0, h, 0)
+    def row_map(j, b, *_):
+        return (b, 0, 0, 0)
+
+    call = _paged_call(
+        _prefix_kernel, (page_map, row_map), 5, (n_blocks, B), n_blocks, (B,), q,
+        k_pages, v_pages, k_new, v_new, scale, softcap, window, interpret,
     )
-    head_spec = pl.BlockSpec(
-        (1, C, G, hd), lambda h, j, b, tables, pos, flags, reps, nsh: (b, 0, h, 0)
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(Hkv, n_blocks, B),
-        in_specs=[head_spec, page_spec, page_spec, row_spec, row_spec],
-        out_specs=[head_spec, page_spec, page_spec],
-        scratch_shapes=[
-            pltpu.VMEM((B, C * G, hd), jnp.float32),
-            pltpu.VMEM((B, C * G), jnp.float32),
-            pltpu.VMEM((B, C * G), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, C, Hq, hd), q.dtype),
-            jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-            jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
-        ],
-        # operand indices count the scalar-prefetch args: pages -> page outs
-        input_output_aliases={6: 1, 7: 2},
-        interpret=interpret,
-    )(
+    return call(
         block_tables,
         pos,
         flags,
@@ -346,7 +420,7 @@ def paged_attention(
     scale: Optional[float] = None,
     softcap: float = 0.0,
     window: int = 0,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused paged append + decode attention.
 
@@ -355,57 +429,23 @@ def paged_attention(
     k_new/v_new: (B, C, Hkv, hd) rope'd chunk K/V; pos: (B,) int32 write
     positions; ``is_global`` may be traced (per-layer sliding-window
     flag). Returns ``(out (B, C, Hq, hd), k_pages, v_pages)`` with the
-    pages updated in place (aliased).
+    pages updated in place (aliased). ``interpret`` has no default: only
+    a caller off the TPU asks for the Pallas interpreter.
     """
-    B, C, Hq, hd = q.shape
-    bs, Hkv = k_pages.shape[1], k_pages.shape[2]
-    G = Hq // Hkv
-    assert Hq % Hkv == 0, "GQA requires q heads to divide over kv heads"
-    assert pos.shape == (B,), "pos must be a (B,) vector (broadcast scalars)"
+    B = q.shape[0]
+    if pos.shape != (B,):
+        raise ValueError("pos must be a (B,) vector (broadcast scalars)")
     n_blocks = block_tables.shape[1]
-    if scale is None:
-        scale = hd**-0.5
     flags = jnp.asarray(is_global, jnp.int32).reshape(1)
 
-    kernel = functools.partial(
-        _paged_kernel,
-        scale=scale,
-        softcap=softcap,
-        window=window,
-        bs=bs,
-        C=C,
-        G=G,
-        n_blocks=n_blocks,
+    def page_map(b, j, tables, pos, flags):
+        return (tables[b, j], 0, 0, 0)
+
+    def row_map(b, j, *_):
+        return (b, 0, 0, 0)
+
+    call = _paged_call(
+        _paged_kernel, (page_map, row_map), 3, (B, n_blocks), n_blocks, (), q,
+        k_pages, v_pages, k_new, v_new, scale, softcap, window, interpret,
     )
-    page_spec = pl.BlockSpec(
-        (1, bs, 1, hd), lambda b, h, j, tables, pos, flags: (tables[b, j], 0, h, 0)
-    )
-    row_spec = pl.BlockSpec(
-        (1, C, 1, hd), lambda b, h, j, tables, pos, flags: (b, 0, h, 0)
-    )
-    head_spec = pl.BlockSpec(
-        (1, C, G, hd), lambda b, h, j, tables, pos, flags: (b, 0, h, 0)
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, Hkv, n_blocks),
-        in_specs=[head_spec, page_spec, page_spec, row_spec, row_spec],
-        out_specs=[head_spec, page_spec, page_spec],
-        scratch_shapes=[
-            pltpu.VMEM((C * G, hd), jnp.float32),
-            pltpu.VMEM((C * G,), jnp.float32),
-            pltpu.VMEM((C * G,), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, C, Hq, hd), q.dtype),
-            jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-            jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
-        ],
-        # operand indices count the scalar-prefetch args: pages -> page outs
-        input_output_aliases={4: 1, 5: 2},
-        interpret=interpret,
-    )(block_tables, pos, flags, q, k_pages, v_pages, k_new, v_new)
+    return call(block_tables, pos, flags, q, k_pages, v_pages, k_new, v_new)

@@ -1,5 +1,11 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# a dry run compiles for 512 virtual CPU devices and never takes an
+# accelerator: pin the CPU, and add the device count to any XLA flags
+# already set instead of replacing them
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(
+    f for f in (os.environ.get("XLA_FLAGS", ""),
+                "--xla_force_host_platform_device_count=512") if f)
 
 """Multi-pod dry run: prove the distribution config is coherent.
 
@@ -13,8 +19,9 @@ Roofline numbers are scan-corrected via per-layer probe compiles (see
 launch/roofline.py): XLA counts a lax.scan body once, so we compile
 1-layer and 2-layer variants, scanned and unrolled, and combine.
 
-NOTE the XLA_FLAGS line above MUST precede any jax import: jax locks the
-device count at first init. This flag is set here and ONLY here.
+NOTE the environment lines above MUST precede any jax import: jax locks
+the platform and device count at first init. They are set here and ONLY
+here.
 
 Usage:
   python -m repro.launch.dryrun --arch mistral-nemo-12b --shape train_4k

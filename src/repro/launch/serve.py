@@ -6,7 +6,16 @@ batches; the engine re-plans per batch through the session's plan cache
 and logs the Eq.-6 transition at the bucket boundary.
 
   PYTHONPATH=src python -m repro.launch.serve --arch deepseek-moe-16b \
-      --chip a6000 --devices 4 --prompt-len 512 --gen 32 --requests 8
+      --layers 4 --prompt-len 512 --gen 32 --requests 8 --continuous
+
+By default the planned config runs at its own dtype on the devices JAX
+reports (a (1, n) ("data", "model") mesh for n > 1), with the planner's
+chip model derived from the accelerator; ``--layers`` cuts its depth.
+``--reduced`` runs the tiny float32 variant instead — the CPU and test
+path, where ``--chip`` names the hardware to plan for:
+
+  PYTHONPATH=src python -m repro.launch.serve --reduced --chip a6000 \
+      --devices 1 --requests 6 --batch 3 --gen 8
 
 ``--source`` swaps the strategy source: the ILP planner (default), the
 static TP/EP baselines, or a pinned plan via --plan
@@ -35,20 +44,31 @@ import logging
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.configs import get_config
 from repro.core import HAPSession, Workload
 from repro.core.latency import cached_latency_model
 from repro.core.session import round_up
-from repro.models import init_params
+from repro.launch.runtime import planner_chip, use_compile_cache
+from repro.models import init_params, param_shardings
 from repro.serving import Request
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="deepseek-moe-16b")
-    ap.add_argument("--chip", default="a6000")
-    ap.add_argument("--devices", type=int, default=4)
+    ap.add_argument("--chip", default=None,
+                    help="planner chip model (default: from the TPU's "
+                         "device kind; required on other platforms)")
+    ap.add_argument("--devices", type=int, default=None,
+                    help="devices to plan for and run on (default: all "
+                         "JAX reports)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="depth cut of the served config (0 = all layers)")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the tiny float32 variant of --arch (the "
+                         "CPU and test path); planning stays full-scale")
     ap.add_argument("--prompt-len", type=int, default=512)
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--requests", type=int, default=8)
@@ -134,14 +154,30 @@ def main() -> None:
     args = ap.parse_args()
     logging.basicConfig(
         level=logging.INFO, format="%(name)s: %(message)s")
+    use_compile_cache()
 
     full_cfg = get_config(args.arch)
+    if args.layers:
+        full_cfg = dataclasses.replace(full_cfg, num_layers=args.layers)
     if args.source == "fixed" and not args.plan:
         ap.error("--source fixed requires --plan")
+    devices = jax.devices()
+    n_dev = args.devices or len(devices)
+    if n_dev > len(devices):
+        ap.error(f"--devices {n_dev}: JAX reports {len(devices)}")
+    chip = args.chip
+    if chip is None:
+        if devices[0].platform != "tpu":
+            ap.error(f"--chip is required on {devices[0].platform}")
+        chip = planner_chip(devices[0])
+    mesh = (jax.make_mesh((1, n_dev), ("data", "model"),
+                          devices=devices[:n_dev],
+                          axis_types=(AxisType.Auto,) * 2)
+            if n_dev > 1 else None)
     source = args.plan if args.plan else (
         None if args.source == "ilp" else args.source)
-    session = HAPSession(full_cfg, args.chip, args.devices, source=source,
-                         model=cached_latency_model(args.chip),
+    session = HAPSession(full_cfg, chip, n_dev, source=source,
+                         model=cached_latency_model(chip), mesh=mesh,
                          prompt_bucket=args.prompt_bucket,
                          gen_bucket=max(args.gen, 1))
 
@@ -163,9 +199,14 @@ def main() -> None:
     print(f"predicted speedup vs static TP: {t_tp / t_hap:.2f}x "
           f"(ILP {plan.ilp_time*1e3:.0f} ms)")
 
-    # execution on local devices uses the reduced config (dev box)
-    cfg = dataclasses.replace(full_cfg.reduced(), dtype="float32")
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    cfg = (dataclasses.replace(full_cfg.reduced(), dtype="float32")
+           if args.reduced else full_cfg)
+    # created in place on the mesh, in the headline plan's first layout
+    layout = plan.to_sharding_plan(
+        mesh, cfg, phase="decode" if args.continuous else "prefill")
+    params = init_params(cfg, jax.random.PRNGKey(0),
+                         shardings=param_shardings(cfg, layout)
+                         if mesh is not None else None)
     if args.prefix_cache and not args.continuous:
         ap.error("--prefix-cache requires --continuous (paged serving)")
     if args.kv_overcommit and not args.continuous:

@@ -225,6 +225,8 @@ def attention_block(x: jax.Array, w: AttnTemps, cfg: ModelConfig,
         shard_axes = plan.attn_kernel_axes(cfg.num_heads, k.shape[2])
         use_kernel = use_kernel and shard_axes is not None
 
+    if not use_kernel:
+        kernel_ops.DISPATCH_COUNTS["flash.ref"] += 1
     if use_kernel:
         out = kernel_ops.flash_attention(
             q, k, v, is_global=is_global, window=cfg.sliding_window,

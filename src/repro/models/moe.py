@@ -29,9 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.kernels import ops as kernel_ops
-from repro.sharding.specs import SHARD_MAP_KW as _SHARD_MAP_KW
 from repro.sharding.specs import ExpertReplication  # noqa: F401 (re-export)
-from repro.sharding.specs import shard_map as _shard_map
 from .common import activation_fn, glu_ffn
 
 
@@ -280,12 +278,12 @@ def _moe_ep_shardmap(x_flat, moe_p, cfg: ModelConfig, plan, backend=None):
         y = combine(y_buf, fe, pe, keep, fg, T_loc)
         return y, jax.lax.pmean(aux, ep_ax), idx
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(dp_spec, P(None, None), w_spec(wig), w_spec(wiu),
                   w_spec(wo)),
         out_specs=(dp_spec, P(), P(tok_axes or None, None)),
-        **_SHARD_MAP_KW)
+        check_vma=False)
     y, aux, idx = fn(x_flat, moe_p["router"], wig, wiu, wo)
     return y, jnp.mean(aux), idx
 

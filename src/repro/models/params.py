@@ -229,41 +229,54 @@ def abstract_params(cfg: ModelConfig, dtype: Optional[str] = None):
 
 
 def init_params(cfg: ModelConfig, key: jax.Array,
-                dtype: Optional[str] = None) -> Params:
-    """Real initialization (used for smoke tests / examples / training)."""
+                dtype: Optional[str] = None, shardings=None) -> Params:
+    """Random initialization from ``key``.
+
+    ``shardings`` (a ``Sharding``, or a tree of them matching
+    ``param_shapes``) creates every leaf already in place inside one jit,
+    so a model larger than one device is never materialized whole on
+    device 0. Without it the leaves are made eagerly on the default device.
+    """
     dt = _dtype(cfg, dtype)
     shapes = param_shapes(cfg)
-    flat, treedef = jax.tree.flatten(shapes,
-                                     is_leaf=lambda x: isinstance(x, tuple))
-    keys = jax.random.split(key, len(flat))
+    paths, treedef = jax.tree.flatten_with_path(
+        shapes, is_leaf=lambda x: isinstance(x, tuple))
 
-    # jax.tree.flatten_with_path only exists in newer jax; tree_util is
-    # stable across the versions we support.
-    paths = jax.tree_util.tree_flatten_with_path(
-        shapes, is_leaf=lambda x: isinstance(x, tuple))[0]
+    def build(key):
+        keys = jax.random.split(key, len(paths))
+        leaves = []
+        for (path, shape), k in zip(paths, keys):
+            name = str(path[-1].key) if hasattr(path[-1], "key") \
+                else str(path[-1])
+            if "norm" in name or name.startswith("ln"):
+                leaves.append(jnp.ones(shape, dt))
+            elif name == "A_log":
+                # mamba1: A = -exp(A_log), init A_log = log(1..N)
+                n = shape[-1]
+                a = jnp.tile(jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32)),
+                             shape[:-1] + (1,))
+                leaves.append(a.astype(dt))
+            elif name == "D":
+                leaves.append(jnp.ones(shape, dt))
+            elif name in ("conv_b", "dt_b"):
+                leaves.append(jnp.zeros(shape, dt))
+            elif name == "embed":
+                leaves.append(jax.random.normal(k, shape, dt) * 0.02)
+            else:
+                fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
+                std = 1.0 / math.sqrt(max(fan_in, 1))
+                leaves.append(jax.random.normal(k, shape, dt) * std)
+        return jax.tree.unflatten(treedef, leaves)
 
-    leaves = []
-    for (path, shape), k in zip(paths, keys):
-        name = str(path[-1].key) if hasattr(path[-1], "key") else str(path[-1])
-        if "norm" in name or name.startswith("ln"):
-            leaves.append(jnp.ones(shape, dt))
-        elif name == "A_log":
-            # mamba1: A = -exp(A_log), init A_log = log(1..N)
-            n = shape[-1]
-            a = jnp.tile(jnp.log(jnp.arange(1, n + 1, dtype=jnp.float32)),
-                         shape[:-1] + (1,))
-            leaves.append(a.astype(dt))
-        elif name == "D":
-            leaves.append(jnp.ones(shape, dt))
-        elif name in ("conv_b", "dt_b"):
-            leaves.append(jnp.zeros(shape, dt))
-        elif name == "embed":
-            leaves.append(jax.random.normal(k, shape, dt) * 0.02)
-        else:
-            fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
-            std = 1.0 / math.sqrt(max(fan_in, 1))
-            leaves.append(jax.random.normal(k, shape, dt) * std)
-    return jax.tree.unflatten(treedef, leaves)
+    if shardings is None:
+        return build(key)
+    return jax.jit(build, out_shardings=shardings)(key)
+
+
+def param_shardings(cfg: ModelConfig, plan) -> Dict[str, Any]:
+    """``NamedSharding`` tree for ``param_pspecs`` on the plan's mesh."""
+    return jax.tree.map(plan.sharding, param_pspecs(cfg, plan),
+                        is_leaf=lambda x: isinstance(x, P))
 
 
 def count_params(params: Params) -> int:

@@ -30,18 +30,6 @@ from typing import Optional, Tuple  # noqa: F401
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map (with check_vma) only exists in newer jax; older versions
-# ship it under jax.experimental with the check_rep spelling. The single
-# compat shim for every shard_map consumer (kernel seam, EP experts).
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-    SHARD_MAP_KW = {"check_vma": False}
-else:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-    SHARD_MAP_KW = {"check_rep": False}
-
-
 @dataclasses.dataclass(frozen=True)
 class KernelShardAxes:
     """Plan -> shard_map axis resolution for the kernel seam (DESIGN.md §4c).

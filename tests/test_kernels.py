@@ -28,7 +28,7 @@ def test_flash_attention_shapes(B, Hq, Hkv, Sq, Sk, hd, dtype):
     q = jax.random.normal(ks[0], (B, Hq, Sq, hd), dtype)
     k = jax.random.normal(ks[1], (B, Hkv, Sk, hd), dtype)
     v = jax.random.normal(ks[2], (B, Hkv, Sk, hd), dtype)
-    out = flash_attention(q, k, v, bq=32, bk=32)
+    out = flash_attention(q, k, v, bq=32, bk=32, interpret=True)
     expect = ref.flash_attention_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32),
@@ -45,7 +45,7 @@ def test_flash_attention_masks(causal, window, softcap):
     k = jax.random.normal(ks[1], (1, 2, 64, 32), jnp.float32)
     v = jax.random.normal(ks[2], (1, 2, 64, 32), jnp.float32)
     out = flash_attention(q, k, v, causal=causal, window=window,
-                          softcap=softcap, bq=16, bk=16)
+                          softcap=softcap, bq=16, bk=16, interpret=True)
     expect = ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                      softcap=softcap)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
@@ -69,7 +69,8 @@ def test_flash_attention_matches_model_attention():
     kern_out = flash_attention(q.transpose(0, 2, 1, 3),
                                k.transpose(0, 2, 1, 3),
                                v.transpose(0, 2, 1, 3),
-                               bq=16, bk=16).transpose(0, 2, 1, 3)
+                               bq=16, bk=16,
+                               interpret=True).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(kern_out), np.asarray(model_out),
                                atol=3e-5, rtol=3e-5)
 
@@ -82,7 +83,7 @@ def test_grouped_matmul(E, C, d, f, dtype):
     k1, k2 = jax.random.split(jax.random.PRNGKey(0))
     lhs = jax.random.normal(k1, (E, C, d), dtype)
     rhs = jax.random.normal(k2, (E, d, f), dtype)
-    out = grouped_matmul(lhs, rhs, bc=16, bf=16, bk=32)
+    out = grouped_matmul(lhs, rhs, bc=16, bf=16, bk=32, interpret=True)
     expect = ref.grouped_matmul_ref(lhs, rhs)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32),
@@ -97,7 +98,8 @@ def test_int4_dequant(G, gs, bg, out_dtype):
                             jnp.int32).astype(jnp.uint8)
     sc = jax.random.uniform(key, (G, 1), jnp.float32, 0.01, 0.2)
     zp = jax.random.uniform(key, (G, 1), jnp.float32, -1, 1)
-    out = int4_dequant(pk, sc, zp, out_dtype=out_dtype, bg=bg)
+    out = int4_dequant(pk, sc, zp, out_dtype=out_dtype, bg=bg,
+                       interpret=True)
     expect = ref.int4_dequant_ref(pk, sc, zp, out_dtype=out_dtype)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32), atol=1e-2)

@@ -164,14 +164,12 @@ def test_pipelined_ffn_clamps_chunks_to_capacity():
     shard_map context, so wrap one over a 1-wide mesh."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.sharding.specs import SHARD_MAP_KW, shard_map
-
     mesh = jax.make_mesh((1,), ("model",))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda b: ops.pipelined_ep_ffn(b, lambda s: s * 2.0,
                                        ep_axis="model", chunks=8),
         mesh=mesh, in_specs=P("model"), out_specs=P("model"),
-        **SHARD_MAP_KW)
+        check_vma=False)
     ops.reset_dispatch_counts()
     out = fn(jnp.ones((4, 2, 8)))
     assert out.shape == (4, 2, 8)
@@ -187,14 +185,12 @@ def test_a2a_ppermute_identity_on_single_device():
     parity tests above rely on."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.sharding.specs import SHARD_MAP_KW, shard_map
-
     mesh = jax.make_mesh((1,), ("model",))
     x = jax.random.normal(jax.random.PRNGKey(4), (4, 6, 8))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda b: ops.a2a_ppermute(b, "model", split=0, concat=1),
         mesh=mesh, in_specs=P("model"), out_specs=P("model"),
-        **SHARD_MAP_KW)
+        check_vma=False)
     np.testing.assert_array_equal(np.asarray(fn(x)), np.asarray(x))
 
 
@@ -209,14 +205,13 @@ def test_a2a_ppermute_matches_lax_all_to_all():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.kernels import ops
-        from repro.sharding.specs import SHARD_MAP_KW, shard_map
 
         mesh = jax.make_mesh((4,), ('ep',))
         x = jax.random.normal(jax.random.PRNGKey(0), (4, 8, 12, 3))
 
         def wrap(f):
-            return shard_map(f, mesh=mesh, in_specs=P('ep'),
-                             out_specs=P('ep'), **SHARD_MAP_KW)
+            return jax.shard_map(f, mesh=mesh, in_specs=P('ep'),
+                                 out_specs=P('ep'), check_vma=False)
 
         for split, concat in ((0, 1), (1, 0)):
             mine = wrap(lambda b: ops.a2a_ppermute(

@@ -42,6 +42,8 @@ def _case(key, B, C, Hq, Hkv, hd, bs, nb, N, dtype=jnp.float32):
     (1, 8, 2, 2, 32, 4, 4),      # chunk append spanning pages, MHA
     (3, 4, 4, 1, 16, 8, 2),      # MQA
     (2, 5, 8, 4, 8, 4, 3),       # uneven chunk vs block size
+    (3, 1, 4, 4, 16, 8, 3),      # decode, MHA over several kv heads (G=1,
+    #                              DeepSeek-MoE's shape)
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_kernel_matches_ref(B, C, Hq, Hkv, hd, bs, nb, dtype):
@@ -54,7 +56,7 @@ def test_paged_kernel_matches_ref(B, C, Hq, Hkv, hd, bs, nb, dtype):
     out_r, k_r, v_r = ref.paged_attention_ref(q, kp, vp, tables, kn, vn, pos,
                                               scale=hd ** -0.5)
     out_p, k_p, v_p = paged_attention(q, kp, vp, tables, kn, vn, pos,
-                                      scale=hd ** -0.5)
+                                      scale=hd ** -0.5, interpret=True)
     np.testing.assert_allclose(np.asarray(out_p, np.float32),
                                np.asarray(out_r, np.float32),
                                atol=tol, rtol=tol)
@@ -74,7 +76,7 @@ def test_paged_kernel_masks(window, is_global, softcap):
         scale=16 ** -0.5, softcap=softcap, window=window)
     out_p, _, _ = paged_attention(
         q, kp, vp, tables, kn, vn, pos, is_global,
-        scale=16 ** -0.5, softcap=softcap, window=window)
+        scale=16 ** -0.5, softcap=softcap, window=window, interpret=True)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
                                atol=2e-5, rtol=2e-5)
 
@@ -88,7 +90,7 @@ def test_paged_kernel_traced_is_global():
     @jax.jit
     def both(flag):
         o, _, _ = paged_attention(q, kp, vp, tables, kn, vn, pos, flag,
-                                  scale=16 ** -0.5, window=4)
+                                  scale=16 ** -0.5, window=4, interpret=True)
         return o
 
     for flag in (True, False):
@@ -108,7 +110,7 @@ def test_paged_kernel_drained_row_leaves_live_pages_alone():
     out_r, k_r, v_r = ref.paged_attention_ref(q, kp, vp, tables, kn, vn, pos,
                                               scale=2 ** -0.5)
     out_p, k_p, v_p = paged_attention(q, kp, vp, tables, kn, vn, pos,
-                                      scale=2 ** -0.5)
+                                      scale=2 ** -0.5, interpret=True)
     np.testing.assert_allclose(np.asarray(out_p)[0], np.asarray(out_r)[0],
                                atol=2e-5, rtol=2e-5)  # live row agrees
     np.testing.assert_array_equal(np.asarray(k_p)[1:], np.asarray(k_r)[1:])

@@ -219,6 +219,7 @@ def _prefix_case(key, B, C, Hq, Hkv, hd, bs, nb, N, dtype=jnp.float32):
 @pytest.mark.parametrize("B,C,Hq,Hkv,hd,bs,nb", [
     (3, 1, 4, 2, 16, 4, 3),       # plain decode, GQA, 3 rows / 1 group
     (2, 5, 4, 4, 8, 4, 4),        # chunk append spanning pages, MHA
+    (3, 1, 4, 4, 16, 4, 3),       # decode, MHA over several kv heads
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_prefix_kernel_matches_ref_and_plain_paged(B, C, Hq, Hkv, hd, bs, nb,
@@ -242,7 +243,8 @@ def test_prefix_kernel_matches_ref_and_plain_paged(B, C, Hq, Hkv, hd, bs, nb,
     # oracle vs plain paged: exact (same physical reads, same order)
     np.testing.assert_array_equal(np.asarray(out_r), np.asarray(out_plain))
     out_p, k_p, v_p = prefix_paged_attention(
-        q, kp, vp, tables, kn, vn, pos, reps, nsh, scale=hd ** -0.5)
+        q, kp, vp, tables, kn, vn, pos, reps, nsh, scale=hd ** -0.5,
+        interpret=True)
     np.testing.assert_allclose(np.asarray(out_p, np.float32),
                                np.asarray(out_r, np.float32),
                                atol=tol, rtol=tol)
@@ -262,7 +264,7 @@ def test_prefix_kernel_masks(window, is_global, softcap):
         scale=16 ** -0.5, softcap=softcap, window=window)
     out_p, _, _ = prefix_paged_attention(
         q, kp, vp, tables, kn, vn, pos, reps, nsh, is_global,
-        scale=16 ** -0.5, softcap=softcap, window=window)
+        scale=16 ** -0.5, softcap=softcap, window=window, interpret=True)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
                                atol=2e-5, rtol=2e-5)
 
