@@ -40,6 +40,12 @@ def _strip_l(tree):
                                                          jax.ShapeDtypeStruct)))
 
 
+def _one_l(tree):
+    """Cut the leading stacked-layer dim of shapes to one layer."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((1,) + x.shape[1:], x.dtype), tree)
+
+
 def _named(mesh, tree):
     return jax.tree.map(lambda s: NamedSharding(mesh, s), tree,
                         is_leaf=lambda x: isinstance(x, P))
@@ -47,8 +53,9 @@ def _named(mesh, tree):
 
 def probe_layer_costs(cfg, shape_name: str, mesh, plan) -> roofline.Costs:
     seq, batch, kind = INPUT_SHAPES[shape_name]
-    ap_layer = _strip_l(abstract_params(cfg)["layers"])
-    ps_layer = _strip_l(param_pspecs(cfg, plan)["layers"])
+    ap_stack = abstract_params(cfg)["layers"]
+    ps_stack = param_pspecs(cfg, plan)["layers"]
+    ap_layer, ps_layer = _strip_l(ap_stack), _strip_l(ps_stack)
     dt = jnp.dtype(cfg.dtype)
 
     if cfg.frontend == "vision" and kind != "decode":
@@ -84,16 +91,21 @@ def probe_layer_costs(cfg, shape_name: str, mesh, plan) -> roofline.Costs:
             in_sh = (_named(mesh, ps_layer), NamedSharding(mesh, act_spec))
     else:  # decode
         x = jax.ShapeDtypeStruct((batch, 1, cfg.d_model), dt)
-        per_layer: Dict[str, Any] = {"lp": ap_layer,
-                                     "flag": jax.ShapeDtypeStruct((), bool)}
-        sh: Dict[str, Any] = {"lp": ps_layer, "flag": P()}
+        # the engine's decode body over a stack of one layer, at index 0
+        kv = kv_sh = None
         if cfg.has_attention:
             kv_dt = jnp.dtype(cfg.kv_cache_dtype) if cfg.kv_cache_dtype \
                 else dt
-            per_layer["k"] = jax.ShapeDtypeStruct(
-                (batch, seq, cfg.num_kv_heads, cfg.head_dim), kv_dt)
-            per_layer["v"] = per_layer["k"]
-            sh["k"] = sh["v"] = plan.cache_spec_bshd()
+            k = jax.ShapeDtypeStruct(
+                (1, batch, seq, cfg.num_kv_heads, cfg.head_dim), kv_dt)
+            kv, kv_sh = (k, k), (plan.kv_cache_spec(),) * 2
+        lp, stacked = T.split_decode_stacks(cfg, _one_l(ap_stack), kv)
+        lp_sh, stacked_sh = T.split_decode_stacks(cfg, ps_stack, kv_sh)
+        per_layer: Dict[str, Any] = {
+            "lp": _strip_l(lp), "flag": jax.ShapeDtypeStruct((), bool),
+            "layer": jax.ShapeDtypeStruct((), jnp.int32)}
+        sh: Dict[str, Any] = {"lp": _strip_l(lp_sh), "flag": P(),
+                              "layer": P()}
         if cfg.has_mamba:
             per_layer["conv"] = jax.ShapeDtypeStruct(
                 (batch, cfg.ssm_conv - 1, cfg.ssm_d_inner), dt)
@@ -103,13 +115,13 @@ def probe_layer_costs(cfg, shape_name: str, mesh, plan) -> roofline.Costs:
             sh["ssm"] = P(*tuple(plan.ssm_cache_spec())[1:])
         pos = jax.ShapeDtypeStruct((), jnp.int32)
 
-        def probe(pl, xx, pos_):
-            body = T.make_decode_body(cfg, plan, pos_)
+        def probe(pl, st, xx, pos_):
+            body = T.make_decode_body(cfg, plan, pos_, st)
             return body(xx, pl)
-        args = (per_layer, x, pos)
+        args = (per_layer, stacked, x, pos)
         dec_spec = P(plan.dp, None, None)
-        in_sh = (_named(mesh, sh), NamedSharding(mesh, dec_spec),
-                 NamedSharding(mesh, P()))
+        in_sh = (_named(mesh, sh), _named(mesh, stacked_sh),
+                 NamedSharding(mesh, dec_spec), NamedSharding(mesh, P()))
 
     with mesh:
         compiled = jax.jit(probe, in_shardings=in_sh).lower(*args).compile()

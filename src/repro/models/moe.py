@@ -41,6 +41,10 @@ class MoEOut(NamedTuple):
     route_idx: Optional[jax.Array] = None
 
 
+# the routed experts' weight leaves under ``moe`` (stacked (L, E, ...))
+EXPERT_LEAVES = ("wi_gate", "wi_up", "wo")
+
+
 def capacity(num_tokens: int, cfg: ModelConfig) -> int:
     c = math.ceil(num_tokens * cfg.top_k / cfg.n_routed_experts
                   * cfg.capacity_factor)
@@ -71,6 +75,7 @@ def pipeline_chunks(C_loc: int, ep_size: int, knob: int = 0) -> int:
     return 1
 
 
+@jax.named_scope("router")
 def route(x_flat: jax.Array, router_w: jax.Array, cfg: ModelConfig):
     """Top-k routing. x_flat: (T, d) -> gates (T,k), idx (T,k), aux_loss."""
     logits = jnp.einsum("td,de->te", x_flat.astype(jnp.float32),
@@ -329,19 +334,23 @@ def apply_moe(x: jax.Array, moe_p: Dict[str, Any], cfg: ModelConfig,
     B, S, d = x.shape
     x_flat = x.reshape(B * S, d)
 
-    if plan is None or plan.is_null:
-        y, aux, idx = _moe_local(x_flat, moe_p, cfg, backend=backend,
-                                 rep=_active_replication(plan))
-    elif plan.ffn_mode == "ep" and plan.ep_axis is not None:
-        y, aux, idx = _moe_ep_shardmap(x_flat, moe_p, cfg, plan,
-                                       backend=backend)
-    else:
-        y, aux, idx = _moe_tp(x_flat, moe_p, cfg, plan, backend=backend)
+    # device-trace names: "experts" (dispatch, expert FFNs, combine) with
+    # "router" inside it (``route``), and "shared_experts"
+    with jax.named_scope("experts"):
+        if plan is None or plan.is_null:
+            y, aux, idx = _moe_local(x_flat, moe_p, cfg, backend=backend,
+                                     rep=_active_replication(plan))
+        elif plan.ffn_mode == "ep" and plan.ep_axis is not None:
+            y, aux, idx = _moe_ep_shardmap(x_flat, moe_p, cfg, plan,
+                                           backend=backend)
+        else:
+            y, aux, idx = _moe_tp(x_flat, moe_p, cfg, plan, backend=backend)
 
     if cfg.n_shared_experts:
-        y_shared = glu_ffn(x_flat, moe_p["shared_wi_gate"],
-                           moe_p["shared_wi_up"], moe_p["shared_wo"],
-                           cfg.activation)
+        with jax.named_scope("shared_experts"):
+            y_shared = glu_ffn(x_flat, moe_p["shared_wi_gate"],
+                               moe_p["shared_wi_up"], moe_p["shared_wo"],
+                               cfg.activation)
         y = y + y_shared
     return MoEOut(y.reshape(B, S, d), aux * cfg.router_aux_loss_coef,
                   idx)

@@ -102,6 +102,7 @@ def embed_inputs(params, cfg: ModelConfig, batch: Dict[str, jax.Array],
     return x
 
 
+@jax.named_scope("head")
 def unembed(params, cfg: ModelConfig, hidden: jax.Array) -> jax.Array:
     h = rms_norm(hidden, params["final_norm"], cfg.norm_eps)
     if cfg.tie_embeddings:
@@ -186,7 +187,8 @@ def _ffn_full(x, lp, cfg: ModelConfig, plan, backend=None):
 
 def layer_full(x, lp, flag, cfg: ModelConfig, plan, collect_kv: bool = False,
                backend=None):
-    mixed, kv = _mixer_full(x, lp, flag, cfg, plan, collect_kv, backend)
+    with jax.named_scope("attn"):
+        mixed, kv = _mixer_full(x, lp, flag, cfg, plan, collect_kv, backend)
     x = x + mixed
     if plan is not None and not plan.is_null:
         x = plan.constrain(x, plan.act_btd())
@@ -375,13 +377,14 @@ def make_prefill_body(cfg: ModelConfig, plan, backend=None):
 def _prefill_finish(params, cfg: ModelConfig, h, ys, B, S, max_len, plan):
     cache = init_cache(cfg, B, max_len, dtype=h.dtype, plan=plan)
     if cfg.has_attention:
-        k_new = ys["kv"][0].astype(cache.k.dtype)   # (L, B, S, Hkv, hd)
-        v_new = ys["kv"][1].astype(cache.v.dtype)
-        k = jax.lax.dynamic_update_slice(cache.k, k_new, (0, 0, 0, 0, 0))
-        v = jax.lax.dynamic_update_slice(cache.v, v_new, (0, 0, 0, 0, 0))
-        if plan is not None and not plan.is_null:
-            k = plan.constrain(k, plan.kv_cache_spec())
-            v = plan.constrain(v, plan.kv_cache_spec())
+        with jax.named_scope("kv_write"):
+            k_new = ys["kv"][0].astype(cache.k.dtype)   # (L, B, S, Hkv, hd)
+            v_new = ys["kv"][1].astype(cache.v.dtype)
+            k = jax.lax.dynamic_update_slice(cache.k, k_new, (0, 0, 0, 0, 0))
+            v = jax.lax.dynamic_update_slice(cache.v, v_new, (0, 0, 0, 0, 0))
+            if plan is not None and not plan.is_null:
+                k = plan.constrain(k, plan.kv_cache_spec())
+                v = plan.constrain(v, plan.kv_cache_spec())
         cache = cache._replace(k=k, v=v)
     if cfg.has_mamba:
         cache = cache._replace(conv=ys["conv"].astype(cache.conv.dtype),
@@ -526,17 +529,17 @@ def decode_step(params, cfg: ModelConfig, token: jax.Array,
     pos = cache.pos
     flags = _layer_flags(cfg)
 
-    xs: Dict[str, Any] = {"lp": params["layers"], "flag": flags}
-    if cfg.has_attention:
-        xs["k"] = cache.k
-        xs["v"] = cache.v
+    layers, stacked = split_decode_stacks(cfg, params["layers"],
+                                          (cache.k, cache.v))
+    xs: Dict[str, Any] = {"lp": layers, "flag": flags,
+                          "layer": jnp.arange(flags.shape[0])}
     if cfg.has_mamba:
         xs["conv"] = cache.conv
         xs["ssm"] = cache.ssm
 
     collect_routing = collect_routing and cfg.ffn_type == "moe"
-    body = make_decode_body(cfg, plan, pos, cache.block_tables, backend,
-                            prefix_groups=cache.prefix_groups,
+    body = make_decode_body(cfg, plan, pos, stacked, cache.block_tables,
+                            backend, prefix_groups=cache.prefix_groups,
                             collect_routing=collect_routing)
     h, ys = _scan(body, x, xs)
     new_cache = cache._replace(pos=pos + C, route_topk=None)
@@ -550,7 +553,26 @@ def decode_step(params, cfg: ModelConfig, token: jax.Array,
     return logits[:, 0], new_cache
 
 
-def make_decode_body(cfg: ModelConfig, plan, pos, block_tables=None,
+def split_decode_stacks(cfg: ModelConfig, layers, kv):
+    """Split the decode scan's stacked inputs: ``(layers, stacked)``.
+
+    The routed experts' weights and the KV pool ``kv`` = ``(k, v)`` leave
+    ``layers`` for ``stacked``, which the decode body slices per layer
+    under its scopes rather than the scan: the copies then carry the
+    ``experts`` / ``kv_write`` names in a device trace. Works on arrays,
+    abstract shapes and partition specs alike.
+    """
+    stacked: Dict[str, Any] = {}
+    if cfg.ffn_type == "moe":
+        moe = dict(layers["moe"])
+        stacked["experts"] = {n: moe.pop(n) for n in moe_mod.EXPERT_LEAVES}
+        layers = {**layers, "moe": moe}
+    if cfg.has_attention:
+        stacked["kv"] = tuple(kv)
+    return layers, stacked
+
+
+def make_decode_body(cfg: ModelConfig, plan, pos, stacked, block_tables=None,
                      backend=None, prefix_groups=None,
                      collect_routing: bool = False):
     """The decode layer-scan body (exposed for the dry-run cost probe).
@@ -561,19 +583,39 @@ def make_decode_body(cfg: ModelConfig, plan, pos, block_tables=None,
     prefix blocks through their group representative's table (DESIGN.md
     §4d); ``backend`` picks the kernel implementation behind the
     dispatch.
+
+    ``stacked`` (from ``split_decode_stacks``) holds whole-stack leaves
+    the body slices at ``per_layer["layer"]`` itself: ``"experts"`` (the
+    routed expert weights, merged into ``lp["moe"]``) and ``"kv"`` (the
+    K and V pools).
     """
+
+    def take(tree, layer):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(
+                a, layer, keepdims=False, allow_negative_indices=False),
+            tree)
 
     def body(h, per_layer):
         lp, flag = per_layer["lp"], per_layer["flag"]
+        if "experts" in stacked:
+            with jax.named_scope("experts"):
+                lp = {**lp, "moe": {**lp["moe"],
+                                    **take(stacked["experts"],
+                                           per_layer["layer"])}}
+        if "kv" in stacked:
+            with jax.named_scope("kv_write"):
+                k_l, v_l = take(stacked["kv"], per_layer["layer"])
         ys: Dict[str, Any] = {}
         hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
         outs = []
         if cfg.has_attention:
-            w = attn_mod.AttnTemps(**lp["attn"])
-            a_out, k_c, v_c = attn_mod.decode_attention(
-                hn, w, cfg, flag, per_layer["k"], per_layer["v"], pos, plan,
-                block_tables=block_tables, prefix_groups=prefix_groups,
-                backend=backend)
+            with jax.named_scope("attn"):
+                w = attn_mod.AttnTemps(**lp["attn"])
+                a_out, k_c, v_c = attn_mod.decode_attention(
+                    hn, w, cfg, flag, k_l, v_l, pos, plan,
+                    block_tables=block_tables, prefix_groups=prefix_groups,
+                    backend=backend)
             ys["k"], ys["v"] = k_c, v_c
             outs.append(("attn", a_out))
         if cfg.has_mamba:

@@ -60,6 +60,7 @@ backends).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import threading
 import time
@@ -79,9 +80,11 @@ from repro.models import (
     init_cache,
     init_paged_cache,
     merge_cache_rows,
-    prefill,
 )
+from repro.models import prefill as model_prefill
+from repro.models.moe import EXPERT_LEAVES
 from repro.sharding.specs import NULL_PLAN, ExpertReplication, quantized_pspec
+from . import trace
 from .faults import FaultInjector
 from .kv_cache import TRASH_BLOCK, BlockAllocator, BlockTable, OutOfBlocks, blocks_for
 from .prefix_cache import PrefixCache
@@ -96,7 +99,23 @@ from .scheduler import ContinuousScheduler, QueuedRequest
 
 log = logging.getLogger("repro.serving")
 
-_EXPERT_LEAVES = ("wi_gate", "wi_up", "wo")
+
+def _spanned(name: str, attrs=None):
+    """Run the method inside the engine's span ``name``; ``attrs(self,
+    result, *args)`` gives the span's attributes when it records."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def method(self, *args, **kw):
+            with self.trace.span(name) as sp:
+                out = fn(self, *args, **kw)
+                if sp and attrs is not None:
+                    sp.attrs.update(attrs(self, out, *args))
+            return out
+
+        return method
+
+    return wrap
 
 
 @dataclasses.dataclass
@@ -122,6 +141,7 @@ class Completion:
     # tokens were generated before the request was retired.
     status: str = "ok"
     preemptions: int = 0  # times this request was preempted-and-recomputed
+    record: Optional[trace.RequestRecord] = None  # its event times (trace.py)
 
 
 @dataclasses.dataclass
@@ -304,6 +324,9 @@ class InferenceEngine:
         # None/"auto" resolves per platform at dispatch (repro.kernels.ops)
         self.kernel_backend = kernel_backend
         self.stats = EngineStats()
+        # program spans (profiler on) and per-request records (always):
+        # DESIGN.md §4g; ``trace.current()`` finds the newest engine's
+        self.trace = trace.install(trace.Recorder())
         # False until a batch has executed under hap_plan: a pre-seeded
         # plan (engine_from_hap) must count as the *initial* plan, not as
         # a previous batch's layout to transition away from.
@@ -409,51 +432,47 @@ class InferenceEngine:
             self._fn_cache[key] = build()
         return self._fn_cache[key]
 
+    # Each jitted program is a named function: its XLA module (and so its
+    # ops in a device trace) reads ``jit_<name>``.
     def _prefill_fn(self, plan):
         cfg, be = self.cfg, self.kernel_backend
+
+        def prefill(p, b, ml):
+            return model_prefill(p, cfg, b, max_len=ml, plan=plan, backend=be)
+
         return self._jit(
-            ("prefill", plan),
-            lambda: jax.jit(
-                lambda p, b, ml: prefill(p, cfg, b, max_len=ml, plan=plan, backend=be),
-                static_argnums=(2,),
-            ),
+            ("prefill", plan), lambda: jax.jit(prefill, static_argnums=(2,))
         )
 
     def _decode_fn(self, plan):
         cfg, be = self.cfg, self.kernel_backend
         collect = self._tracker is not None
-        return self._jit(
-            ("decode", plan),
-            lambda: jax.jit(
-                lambda p, t, c: decode_step(
-                    p, cfg, t, c, plan=plan, backend=be, collect_routing=collect
-                )
-            ),
-        )
+
+        def decode(p, t, c):
+            return decode_step(
+                p, cfg, t, c, plan=plan, backend=be, collect_routing=collect
+            )
+
+        return self._jit(("decode", plan), lambda: jax.jit(decode))
 
     def _chunk_fn(self, plan):
         """Append one B=1 prefill chunk through a row's block table."""
         cfg, be = self.cfg, self.kernel_backend
-        return self._jit(
-            ("chunk", plan),
-            lambda: jax.jit(
-                lambda p, t, row, c: _chunk_append(p, cfg, t, row, c, plan, be)
-            ),
-        )
+
+        def chunk(p, t, row, c):
+            return _chunk_append(p, cfg, t, row, c, plan, be)
+
+        return self._jit(("chunk", plan), lambda: jax.jit(chunk))
 
     def _cow_fn(self):
         """Copy-on-write fork: duplicate pool pages ``src`` into ``dst``
         across every layer, in one device call (prefix-cache divergence —
         DESIGN.md §4d)."""
-        return self._jit(
-            ("cow",),
-            lambda: jax.jit(
-                lambda k, v, src, dst: (
-                    k.at[:, dst].set(k[:, src]),
-                    v.at[:, dst].set(v[:, src]),
-                )
-            ),
-        )
+
+        def cow(k, v, src, dst):
+            return k.at[:, dst].set(k[:, src]), v.at[:, dst].set(v[:, src])
+
+        return self._jit(("cow",), lambda: jax.jit(cow))
 
     def _fused_fn(self, plan):
         """One fused continuous step: a prefill chunk for the joining row
@@ -515,7 +534,7 @@ class InferenceEngine:
         moe = self.params["layers"].get("moe")
         if moe is None:
             return {}
-        return {k: moe[k] for k in _EXPERT_LEAVES}
+        return {k: moe[k] for k in EXPERT_LEAVES}
 
     def _backup_experts(self) -> None:
         for name, w in self._expert_leaves().items():
@@ -538,7 +557,7 @@ class InferenceEngine:
         pspecs = param_pspecs(self.cfg, sharding_plan)["layers"]["moe"]
         moe = self.params["layers"]["moe"]
         out: Dict[str, Any] = {}
-        for n in _EXPERT_LEAVES:
+        for n in EXPERT_LEAVES:
             spec = quantized_pspec(pspecs[n])
             packed = getattr(moe[n], "packed", None)
             if packed is not None:
@@ -562,7 +581,7 @@ class InferenceEngine:
 
         moe = dict(self.params["layers"]["moe"])
         saved = 0
-        for name in _EXPERT_LEAVES:
+        for name in EXPERT_LEAVES:
             key = f"moe/{name}"
             gs = pick_group_size(int(moe[name].shape[-1]), self.int4_group_size or 128)
             dense_bytes = moe[name].nbytes
@@ -601,13 +620,13 @@ class InferenceEngine:
 
             pspecs = param_pspecs(self.cfg, sharding_plan)["layers"]["moe"]
             shardings = {
-                n: sharding_plan.sharding(pspecs[n]) for n in _EXPERT_LEAVES
+                n: sharding_plan.sharding(pspecs[n]) for n in EXPERT_LEAVES
             }
         moe = dict(self.params["layers"]["moe"])
         q_shardings = (
             self._quantized_shardings(sharding_plan) if self.resident_int4 else {}
         )
-        for name in _EXPERT_LEAVES:
+        for name in EXPERT_LEAVES:
             key = f"moe/{name}"
             if self.resident_int4:
                 # resident leaves stay packed through every transition:
@@ -720,14 +739,14 @@ class InferenceEngine:
 
             pspecs = param_pspecs(self.cfg, sharding_plan)["layers"]["moe"]
             shardings = {
-                n: sharding_plan.sharding(pspecs[n]) for n in _EXPERT_LEAVES
+                n: sharding_plan.sharding(pspecs[n]) for n in EXPERT_LEAVES
             }
         q_shardings = (
             self._quantized_shardings(sharding_plan) if self.resident_int4 else {}
         )
         moe = self.params["layers"]["moe"]
         futures: Dict[str, Any] = {}
-        for name in _EXPERT_LEAVES:
+        for name in EXPERT_LEAVES:
             key = f"moe/{name}"
             if self.resident_int4:
                 futures[name] = self._tx._executor().submit(
@@ -847,7 +866,7 @@ class InferenceEngine:
         """The backup leaf prefetch slices, when per-row restore is
         exact for every expert leaf (row spans must land on INT4 group
         boundaries); None disables prefetch for this engine."""
-        keys = [f"moe/{n}" for n in _EXPERT_LEAVES]
+        keys = [f"moe/{n}" for n in EXPERT_LEAVES]
         if any(self._tx.prefetch_rows_of(k) is None for k in keys):
             return None
         return keys[0]
@@ -876,7 +895,7 @@ class InferenceEngine:
             for layer, experts in enumerate(pred)
             for e in experts
         }
-        n_rows = self._tx.prefetch_rows_of(f"moe/{_EXPERT_LEAVES[0]}")
+        n_rows = self._tx.prefetch_rows_of(f"moe/{EXPERT_LEAVES[0]}")
         rows = {r for r in rows if r < n_rows}
         with self._prefetch_lock:
             # bounded window: evict stale rows the predictor dropped
@@ -900,7 +919,7 @@ class InferenceEngine:
             try:
                 staged = {
                     name: self._tx.prefetch_row(f"moe/{name}", row)
-                    for name in _EXPERT_LEAVES
+                    for name in EXPERT_LEAVES
                 }
             except Exception:
                 log.exception("prefetch pull failed for row %d", row)
@@ -927,7 +946,7 @@ class InferenceEngine:
         hits/misses tally (layer, expert) rows, not row x leaf."""
         with self._prefetch_lock:
             snap = {r: v[name] for r, v in self._prefetch_stage.items()}
-        if name == _EXPERT_LEAVES[0]:
+        if name == EXPERT_LEAVES[0]:
             self.stats.prefetch_hits += len(snap)
             self.stats.prefetch_misses += n_rows - len(snap)
         return snap
@@ -1021,6 +1040,14 @@ class InferenceEngine:
         chunk+decode steps run there — DESIGN.md §4b), so a reused plan
         whose experts already sit in the decode layout moves nothing.
         """
+        with self.trace.span("engine.plan") as sp:
+            hits0 = self.session.hits
+            ms = self._switch_plan(batch_workload, phase)
+            if sp:
+                sp.attrs.update(cache_hit=self.session.hits > hits0, transition_ms=ms)
+            return ms
+
+    def _switch_plan(self, batch_workload: Workload, phase: str) -> float:
         hits0 = self.session.hits
         fb0 = self.session.fallbacks
         self._last_workload = batch_workload
@@ -1082,9 +1109,9 @@ class InferenceEngine:
             None if req.deadline_ms is None
             else self.clock() + req.deadline_ms / 1e3
         )
-        return self.scheduler.submit(
-            req.prompt, req.max_new_tokens, deadline=deadline
-        )
+        uid = self.scheduler.submit(req.prompt, req.max_new_tokens, deadline=deadline)
+        self.trace.submitted(uid)
+        return uid
 
     def cancel(self, uid: int) -> bool:
         """Cancel a request by uid — queued or live. The request retires
@@ -1132,6 +1159,11 @@ class InferenceEngine:
         logits, cache = prefill_fn(self.params, {"tokens": jnp.asarray(toks)}, max_len)
         logits.block_until_ready()
         prefill_ms = (time.perf_counter() - t0) * 1e3
+        for r in batch:
+            rec = self.trace.get(r.uid)
+            if rec is not None:
+                rec.joined, rec.first_chunk, rec.status = t0, t0, "live"
+                rec.chunks += 1
 
         transition_ms = inter_ms + self.transition_expert_layout()
         self.stats.transition_ms_total += transition_ms
@@ -1145,6 +1177,9 @@ class InferenceEngine:
         done = np.zeros((B,), bool)
         for step in range(max_new):
             generated[:, step] = np.where(done, self.eos_id, np.asarray(next_tok))
+            if step == 0:
+                for r in batch:
+                    self._first_token(r.uid)
             if step == max_new - 1:
                 break
             key, sub = jax.random.split(key)
@@ -1166,7 +1201,10 @@ class InferenceEngine:
                 int(t) for t in generated[i, :n] if t != self.eos_id or self.eos_id < 0
             ]
             comps.append(
-                Completion(r.uid, toks_out, prefill_ms, decode_ms, transition_ms)
+                Completion(
+                    r.uid, toks_out, prefill_ms, decode_ms, transition_ms,
+                    record=self.trace.finished(r.uid, "ok"),
+                )
             )
         return comps
 
@@ -1215,6 +1253,13 @@ class InferenceEngine:
         out.extend(self.retire())  # any last terminal completions
         return sorted(out, key=lambda c: c.uid)
 
+    @_spanned(
+        "engine.begin",
+        lambda self, _: {
+            "width": self._live.kv_capacity,
+            "pool_blocks": self._live.allocator.num_blocks - 1 if self.paged else 0,
+        },
+    )
     def _begin_live_batch(self) -> None:
         """Size a fresh live batch from the current queue.
 
@@ -1278,6 +1323,7 @@ class InferenceEngine:
             log.info("live batch: %d slots, KV capacity %d tokens", nslots, cap)
         self.stats.batches += 1
 
+    @_spanned("engine.admit", lambda self, joined, *_: {"joined": len(joined)})
     def admit(self, sampling: SamplingParams) -> List[int]:
         """Admit queue-head requests into freed slots at a step boundary.
 
@@ -1329,11 +1375,17 @@ class InferenceEngine:
         self._plan_ran = True
         return inter_ms
 
+    @_spanned("engine.join", lambda self, _, i, r, *__: {"uid": r.uid})
     def _admit_one(self, i: int, r: QueuedRequest, sampling: SamplingParams) -> None:
         live = self._live
         slot = _Slot(req=r, start=self.scheduler.padded_len(r))
         live.slots[i] = slot
         self.stats.joins += 1
+        rec = self.trace.get(r.uid)
+        if rec is not None:
+            rec.status = "live"
+            if rec.joined is None:
+                rec.joined = time.perf_counter()
 
         if self.paged:
             # reserve the block budget now: worst-case by default
@@ -1419,6 +1471,10 @@ class InferenceEngine:
         )
         logits.block_until_ready()
         slot.prefill_ms = (time.perf_counter() - t0) * 1e3
+        if rec is not None:
+            rec.chunks += 1
+            if rec.first_chunk is None:
+                rec.first_chunk = t0
 
         slot.transition_ms = inter_ms + self.transition_expert_layout()
         self.stats.transition_ms_total += slot.transition_ms
@@ -1449,6 +1505,7 @@ class InferenceEngine:
         live.next_tok[i] = tok0
         if r.max_new_tokens >= 1:
             slot.tokens.append(tok0)
+            self._first_token(r.uid)
         log.info(
             "join uid=%d slot=%d start=%d (queued %d)",
             r.uid,
@@ -1468,12 +1525,38 @@ class InferenceEngine:
         active = live.active()
         if not pending and not active:
             return False
-        if pending:
-            i = min(pending, key=lambda j: live.slots[j].req.uid)
-            self._prefill_chunk_step(i, active, sampling, key)
-        else:
-            self.step_decode(sampling, key)
+        with self.trace.span("engine.step") as sp:
+            if pending:
+                i = min(pending, key=lambda j: live.slots[j].req.uid)
+                s = live.slots[i]
+                rec = self.trace.get(s.req.uid)
+                first = rec is not None and rec.first_chunk is None
+                t_start = sp.started() if first else None
+                ran = self._prefill_chunk_step(i, active, sampling, key)
+                if ran is not None and rec is not None:
+                    rec.chunks += 1
+                    if first:
+                        rec.first_chunk = t_start
+            else:
+                ran = self.step_decode(sampling, key)
+            if sp and ran is not None:
+                sp.attrs.update(self._step_attrs(*ran))
         return True
+
+    def _step_attrs(self, kind: str, rows: List[int], chunk: Optional[tuple]):
+        """The ``engine.step`` span's attributes: the kind run, rows
+        decoding, the chunk's request and real (unpadded) prompt tokens,
+        and the cache positions the step's queries attend in all."""
+        live = self._live
+        ctx = int(sum(live.pos[j] for j in rows))  # pos already advanced: pos+1 before
+        out = {"kind": kind, "rows": len(rows), "chunk_uid": None, "chunk_real": 0}
+        if chunk is not None:
+            r, lo, n = chunk
+            pad = self.scheduler.prompt_bucket(r) - len(r.prompt)
+            out.update(chunk_uid=r.uid, chunk_real=max(0, lo + n - max(lo, pad)))
+            ctx += n * lo + n * (n + 1) // 2
+        out["ctx"] = ctx
+        return out
 
     def _ensure_blocks(
         self, i: int, n_tokens: int, write_from: Optional[int] = None
@@ -1567,6 +1650,10 @@ class InferenceEngine:
         r = s.req
         r.preemptions += 1
         self.stats.preemptions += 1
+        rec = self.trace.get(r.uid)
+        if rec is not None:
+            rec.preemptions += 1
+            rec.status = "queued"
         self.stats.preempted_tokens += len(s.tokens)
         remaining = r.max_new_tokens - len(s.tokens)
         if s.done or remaining <= 0:
@@ -1579,6 +1666,7 @@ class InferenceEngine:
                 Completion(
                     r.uid, toks, s.prefill_ms, s.decode_ms, s.transition_ms,
                     preemptions=r.preemptions,
+                    record=self.trace.finished(r.uid, "ok"),
                 )
             )
             log.info("preempt-complete uid=%d slot=%d", r.uid, j)
@@ -1635,7 +1723,7 @@ class InferenceEngine:
 
     def _prefill_chunk_step(
         self, i: int, active: List[int], sampling: SamplingParams, key
-    ) -> None:
+    ) -> Optional[tuple]:
         """Process the joining row's next prompt chunk; fuse it with a
         decode step over the live rows when there are any and the chunk
         is not the last (the final chunk's logits feed sampling, which
@@ -1644,91 +1732,108 @@ class InferenceEngine:
         Block growth runs through the preemption-aware ``_grow_blocks``
         path: any row — including the joiner itself — may be preempted
         mid-growth to reclaim pool space, so the step re-checks what is
-        still live before touching the device."""
+        still live before touching the device.
+
+        Returns ``(kind, decoding rows, (request, chunk start, chunk
+        length))`` for the step's span, or None when the joiner was
+        preempted instead."""
         live = self._live
         s = live.slots[i]
         chunk = s.pending[0]
         C = len(chunk)
         final = len(s.pending) == 1
-        if not self._grow_blocks(i, s.filled + C, write_from=s.filled):
-            return  # the joiner itself was preempted to cover the pool
-        if active and not final:
-            for j in active:
-                if live.slots[j] is None:
-                    continue
-                self._grow_blocks(
-                    j, int(live.pos[j]) + 1, write_from=int(live.pos[j])
+        grow = active if not final else []
+        with self.trace.span("engine.blocks") as sp:
+            pre = self.stats.preemptions
+            if self._grow_blocks(i, s.filled + C, write_from=s.filled) and grow:
+                active = self._grow_decode_rows(grow)
+            if sp:
+                sp.attrs.update(
+                    rows=1 + len(grow), preemptions=self.stats.preemptions - pre
                 )
-            active = [j for j in active if live.slots[j] is not None]
         if live.slots[i] is None:
-            return  # growing the decode rows preempted the joiner
+            return None  # the joiner was preempted to cover the pool
         s.pending.pop(0)
         plan = self._sharding_for("decode")
         self.stats.prefill_chunks += 1
+        this = (s.req, s.filled, C)
 
         if active and not final:
             fn = self._fused_fn(plan)
-            t0 = time.perf_counter()
-            logits, live.cache = fn(
-                self.params,
-                jnp.asarray(chunk)[None, :],
-                i,
-                jnp.asarray(live.next_tok)[:, None],
-                self._pinned_cache(),
-            )
-            toks = np.asarray(sample(logits, sampling, key))
-            step_ms = (time.perf_counter() - t0) * 1e3
-            s.filled += C
-            live.pos[i] = s.filled
-            # the fused step's wall time is booked once, as the active
-            # rows' decode step (the chunk rides along for free); the
-            # joiner's prefill_ms counts only its unfused chunk steps
-            self.stats.decode_steps += 1
-            self.stats.fused_steps += 1
-            live.cache = self._observe_routing(live.cache)
-            self._apply_sampled(toks, active, step_ms)
-            self._maybe_rebalance()
-            return
+            with self.trace.span("engine.inputs"):
+                chunk_tok = jnp.asarray(chunk)[None, :]
+                dec_tok = jnp.asarray(live.next_tok)[:, None]
+                cache = self._pinned_cache()
+            with self.trace.span("engine.dispatch") as sp:
+                t0 = sp.started()
+                logits, live.cache = fn(self.params, chunk_tok, i, dec_tok, cache)
+            with self.trace.span("engine.sample"):
+                toks = sample(logits, sampling, key)
+            with self.trace.span("engine.sync") as sp:
+                toks = np.asarray(toks)
+            step_ms = (sp.ended() - t0) * 1e3
+            with self.trace.span("engine.book"):
+                s.filled += C
+                live.pos[i] = s.filled
+                # the fused step's wall time is booked once, as the active
+                # rows' decode step (the chunk rides along for free); the
+                # joiner's prefill_ms counts only its unfused chunk steps
+                self.stats.decode_steps += 1
+                self.stats.fused_steps += 1
+                live.cache = self._observe_routing(live.cache)
+                self._apply_sampled(toks, active, step_ms)
+                self._maybe_rebalance()
+            return "fused", active, this
 
         fn = self._chunk_fn(plan)
-        t0 = time.perf_counter()
-        logits, live.cache = fn(
-            self.params, jnp.asarray(chunk)[None, :], i, self._pinned_cache()
-        )
-        logits.block_until_ready()
-        s.filled += C
-        live.pos[i] = s.filled
-        s.prefill_ms += (time.perf_counter() - t0) * 1e3
+        with self.trace.span("engine.inputs"):
+            chunk_tok = jnp.asarray(chunk)[None, :]
+            cache = self._pinned_cache()
+        with self.trace.span("engine.dispatch") as sp:
+            t0 = sp.started()
+            logits, live.cache = fn(self.params, chunk_tok, i, cache)
         if final:
-            # same per-request key chain as a solo run's prefill sample
-            tok0 = int(
-                np.asarray(
-                    sample(
-                        logits,
-                        sampling,
-                        jax.random.fold_in(
-                            jax.random.PRNGKey(sampling.seed), s.req.uid
-                        ),
-                    )
-                )[0]
-            )
-            live.next_tok[i] = tok0
-            if s.req.max_new_tokens >= 1:
-                s.tokens.append(tok0)
-            if live.prefix is not None:
-                # index the completed prompt so later admissions can adopt
-                # it; the cache takes its own block references, so the run
-                # outlives this request's retirement until evicted
-                live.prefix.register(
-                    self.scheduler.pad_batch([s.req])[0][0], s.table.blocks
+            with self.trace.span("engine.sample"):
+                # same per-request key chain as a solo run's prefill sample
+                tok0 = sample(
+                    logits,
+                    sampling,
+                    jax.random.fold_in(jax.random.PRNGKey(sampling.seed), s.req.uid),
                 )
-            log.info(
-                "prefill complete uid=%d slot=%d (%d tokens, %d blocks)",
-                s.req.uid,
-                i,
-                s.filled,
-                len(s.table),
-            )
+        with self.trace.span("engine.sync") as sp:
+            if final:
+                tok0 = int(np.asarray(tok0)[0])
+            else:
+                logits.block_until_ready()
+        s.prefill_ms += (sp.ended() - t0) * 1e3
+        with self.trace.span("engine.book"):
+            s.filled += C
+            live.pos[i] = s.filled
+            if final:
+                live.next_tok[i] = tok0
+                if s.req.max_new_tokens >= 1:
+                    s.tokens.append(tok0)
+                    self._first_token(s.req.uid)
+                if live.prefix is not None:
+                    # index the completed prompt so later admissions can adopt
+                    # it; the cache takes its own block references, so the run
+                    # outlives this request's retirement until evicted
+                    live.prefix.register(
+                        self.scheduler.pad_batch([s.req])[0][0], s.table.blocks
+                    )
+                log.info(
+                    "prefill complete uid=%d slot=%d (%d tokens, %d blocks)",
+                    s.req.uid,
+                    i,
+                    s.filled,
+                    len(s.table),
+                )
+        return "chunk", [], this
+
+    def _first_token(self, uid: int) -> None:
+        rec = self.trace.get(uid)
+        if rec is not None and rec.first_token is None:
+            rec.first_token = time.perf_counter()
 
     def _apply_sampled(
         self, toks: np.ndarray, active: List[int], step_ms: float
@@ -1745,33 +1850,50 @@ class InferenceEngine:
                 continue
             s.tokens.append(t)
 
-    def step_decode(self, sampling: SamplingParams, key=None) -> None:
+    def _grow_decode_rows(self, active: List[int]) -> List[int]:
+        """Grow each decoding row's table to cover its next write (the
+        preemption-aware path); returns the rows still live after."""
+        live = self._live
+        for j in active:
+            if live.slots[j] is not None:
+                self._grow_blocks(j, int(live.pos[j]) + 1, write_from=int(live.pos[j]))
+        return [j for j in active if live.slots[j] is not None]
+
+    def step_decode(self, sampling: SamplingParams, key=None) -> Optional[tuple]:
         """One decode step over the FULL slot set (freed/done rows are
         frozen host-side): constant decode shapes per (plan, slot count),
-        so joins and retirements never trigger a recompile."""
+        so joins and retirements never trigger a recompile. Returns
+        ``("decode", decoding rows, None)`` for the step's span, or None
+        when every row was preempted instead."""
         live = self._live
         active = live.active()
         if self.paged:
-            for j in active:
-                if live.slots[j] is None:
-                    continue
-                self._grow_blocks(
-                    j, int(live.pos[j]) + 1, write_from=int(live.pos[j])
-                )
-            active = [j for j in active if live.slots[j] is not None]
+            with self.trace.span("engine.blocks") as sp:
+                pre = self.stats.preemptions
+                rows = len(active)
+                active = self._grow_decode_rows(active)
+                if sp:
+                    sp.attrs.update(rows=rows, preemptions=self.stats.preemptions - pre)
             if not active:
-                return  # every decode row was preempted to cover the pool
+                return None  # every decode row was preempted to cover the pool
         decode_fn = self._decode_fn(self._sharding_for("decode"))
-        t0 = time.perf_counter()
-        logits, live.cache = decode_fn(
-            self.params, jnp.asarray(live.next_tok)[:, None], self._pinned_cache()
-        )
-        toks = np.asarray(sample(logits, sampling, key))
-        step_ms = (time.perf_counter() - t0) * 1e3
-        self.stats.decode_steps += 1
-        live.cache = self._observe_routing(live.cache)
-        self._apply_sampled(toks, active, step_ms)
-        self._maybe_rebalance()
+        with self.trace.span("engine.inputs"):
+            tok = jnp.asarray(live.next_tok)[:, None]
+            cache = self._pinned_cache()
+        with self.trace.span("engine.dispatch") as sp:
+            t0 = sp.started()
+            logits, live.cache = decode_fn(self.params, tok, cache)
+        with self.trace.span("engine.sample"):
+            toks = sample(logits, sampling, key)
+        with self.trace.span("engine.sync") as sp:
+            toks = np.asarray(toks)
+        step_ms = (sp.ended() - t0) * 1e3
+        with self.trace.span("engine.book"):
+            self.stats.decode_steps += 1
+            live.cache = self._observe_routing(live.cache)
+            self._apply_sampled(toks, active, step_ms)
+            self._maybe_rebalance()
+        return "decode", active, None
 
     def _free_slot(self, i: int) -> "_Slot":
         """Release row ``i``'s resources (blocks back to the pool, mirror
@@ -1789,6 +1911,7 @@ class InferenceEngine:
     def _expired(self, r: QueuedRequest) -> bool:
         return r.deadline is not None and self.clock() >= r.deadline
 
+    @_spanned("engine.reap")
     def _reap_lifecycle(self) -> None:
         """Retire cancelled/expired requests — queued or live — with a
         terminal status (the request-lifecycle contract, DESIGN.md §4f).
@@ -1805,6 +1928,7 @@ class InferenceEngine:
                 Completion(
                     r.uid, list(r.stashed), 0.0, 0.0, 0.0,
                     status=status, preemptions=r.preemptions,
+                    record=self.trace.finished(r.uid, status),
                 )
             )
             log.info("reap queued uid=%d (%s)", r.uid, status)
@@ -1825,6 +1949,7 @@ class InferenceEngine:
                     s.req.uid, toks, s.prefill_ms, s.decode_ms,
                     s.transition_ms, status=status,
                     preemptions=s.req.preemptions,
+                    record=self.trace.finished(s.req.uid, status),
                 )
             )
             log.info(
@@ -1838,6 +1963,7 @@ class InferenceEngine:
         elif status == "deadline":
             self.stats.deadline_expired += 1
 
+    @_spanned("engine.retire", lambda self, comps: {"retired": len(comps)})
     def retire(self) -> List[Completion]:
         """Free slots whose request hit EOS or its output budget; returns
         their completions plus any buffered terminal (cancelled/expired/
@@ -1858,6 +1984,7 @@ class InferenceEngine:
                 Completion(
                     s.req.uid, toks, s.prefill_ms, s.decode_ms, s.transition_ms,
                     preemptions=s.req.preemptions,
+                    record=self.trace.finished(s.req.uid, "ok"),
                 )
             )
             self._free_slot(i)
