@@ -1,17 +1,33 @@
-"""Pallas TPU paged-attention decode kernel (the decode hot spot).
+"""Pallas TPU paged-attention kernels (the decode hot spot).
 
 One fused cache-appending attention step over a block-pooled KV cache
 (DESIGN.md §4b): the chunk's new K/V are scattered into their physical
 pages *and* the row's logical KV view is attended with an on-chip online
 softmax, in a single kernel — the pure-jnp path materializes every row's
 gathered ``(B, max_blocks * block_size, Hkv, hd)`` view in HBM per step,
-which this kernel never does.
+which these kernels never do.
 
-TPU mapping: grid ``(B, max_blocks)`` with the page axis innermost and
-sequential (FlashAttention-2 carry in VMEM scratch). Blocks are whole
-pages ``(1, block_size, Hkv, hd)`` and whole rows ``(1, C, Hq, hd)``, so
-the two minor block dims always equal the array's (the TPU tiling rule
-for blocks); the kernel loops over the kv heads of a page. The per-row
+Single-token decode (``C == 1``, picked from ``q.shape[1]``) has a
+kernel of its own, ``_decode_kernel``: grid ``(B,)``, and per row a loop
+over its live pages only (``0 .. pos[b] // block_size``), ``P`` pages a
+loop step (about ``_KEYS_PER_STEP`` positions). The pool stays in HBM
+(``memory_space=pl.ANY``, aliased in and out); each step's pages are
+fetched by physical id from the scalar-prefetched table with manual async
+copies into a two-deep VMEM buffer, the next step's copies (or the next
+row's first) in flight while this step computes. Per kv head, its G q
+heads are one ``(G, hd)·(hd, P·bs)`` scores product and one online
+softmax update, with the probabilities kept in f32 for the value product.
+The new token's K/V are written by DMA into their one slot of the pool
+and patched into the step's VMEM copy, so the token is attended in the
+step that writes it; no other page is written.
+
+The chunk path (``C > 1``) and ``prefix_paged_attention`` walk pages
+as follows. TPU mapping: grid ``(B, max_blocks)`` with the page axis
+innermost and sequential (FlashAttention-2 carry in VMEM scratch).
+Blocks are whole pages ``(1, block_size, Hkv, hd)`` and whole rows
+``(1, C, Hq, hd)``, so the two minor block dims always equal the array's
+(the TPU tiling rule for blocks); the kernel loops over the kv heads of a
+page. The per-row
 block-table walk rides the BlockSpec index maps: ``block_tables`` and
 ``pos`` are scalar-prefetch operands (SMEM), so each grid step DMAs
 exactly the physical page ``block_tables[b, j]`` into VMEM — pages are
@@ -64,6 +80,10 @@ NEG_INF = -2.0e38  # finite f32 mask value (see module docstring)
 _VMEM_FLOOR = 16 * 2**20
 _VMEM_CAP = 96 * 2**20
 _ROWS_PER_TILE = 128  # chunk rows per step of the in-kernel query loop
+# cache positions a single-token decode loop step walks: of 128, 256 and
+# 512 the fastest on one v5e at Mixtral-8x7B's and DeepSeek-MoE-16B's
+# decode widths (64 rows, 16-token pages, ~800 live positions a row)
+_KEYS_PER_STEP = 128
 
 
 def _vmem_params(block_bytes: int, scratch_bytes: int) -> pltpu.CompilerParams:
@@ -406,6 +426,247 @@ def prefix_paged_attention(
     )
 
 
+def _decode_kernel(
+    tables_ref,
+    pos_ref,
+    flags_ref,
+    q_ref,
+    k_hbm,
+    v_hbm,
+    k_new_ref,
+    v_new_ref,
+    o_ref,
+    k_out,
+    v_out,
+    k_buf,
+    v_buf,
+    sems,
+    slot_ref,
+    acc_ref,
+    m_ref,
+    l_ref,
+    *,
+    P: int,
+    n_blocks: int,
+    bs: int,
+    G: int,
+    Hkv: int,
+    scale: float,
+    softcap: float,
+    window: int,
+):
+    """One row of a single-token decode step: its live pages only, ``P``
+    pages a loop step, fetched by id from the HBM pool into a two-deep
+    VMEM buffer (the next step's pages, or the next row's first, are in
+    flight while this step computes), one scores dot per kv head over
+    all ``G`` of its q heads, and the new token written into its one
+    page."""
+    b = pl.program_id(0)
+    is_global = flags_ref[0] != 0
+
+    def last_page(r):
+        """The last page row ``r`` attends: the one holding its query
+        position, or the table's last if that lies past the table."""
+        return jnp.minimum(pos_ref[r] // bs, n_blocks - 1)
+
+    def first_step(r):
+        """Steps wholly before a sliding window hold nothing row ``r``
+        attends (the last live step always runs)."""
+        if window <= 0:
+            return 0
+        lo = jnp.minimum(jnp.maximum(pos_ref[r] - window + 1, 0) // bs, last_page(r))
+        return jnp.where(is_global, 0, lo // P)
+
+    def fetches(r, step, slot):
+        """The DMAs of row ``r``'s pages ``step*P ..``: pages past its
+        last live one are fetched as that one (finite, masked, and never
+        a page the row does not own)."""
+        last = last_page(r)
+        out = []
+        for p in range(P):
+            page = tables_ref[r, jnp.minimum(step * P + p, last)]
+            for i, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                dst = buf.at[slot, p]
+                out.append(pltpu.make_async_copy(hbm.at[page], dst, sems.at[i, slot]))
+        return out
+
+    @pl.when(b == 0)
+    def _prime():
+        slot_ref[0] = 0
+        for c in fetches(0, first_step(0), 0):
+            c.start()
+
+    pos = pos_ref[b]
+    last = last_page(b)
+    s_last = last // P
+    # the append: the new token's K/V go straight into their one slot of
+    # the pool; a position past the table lands nowhere
+    lands = pos // bs < n_blocks
+    page = tables_ref[b, last]
+    off = pos % bs
+    writes = [
+        pltpu.make_async_copy(new.at[0], out.at[page, pl.ds(off, 1)], sems.at[2, i])
+        for i, (new, out) in enumerate(((k_new_ref, k_out), (v_new_ref, v_out)))
+    ]
+
+    @pl.when(lands)
+    def _write():
+        for c in writes:
+            c.start()
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def step_body(step, carry):
+        slot = slot_ref[0]
+        nxt = 1 - slot
+
+        @pl.when(step < s_last)
+        def _next_step():
+            for c in fetches(b, step + 1, nxt):
+                c.start()
+
+        @pl.when((step == s_last) & (b + 1 < pl.num_programs(0)))
+        def _next_row():
+            for c in fetches(b + 1, first_step(b + 1), nxt):
+                c.start()
+
+        for c in fetches(b, step, slot):
+            c.wait()
+
+        # this step's own copy of the new token, whatever the fetch read
+        @pl.when(lands & (step == s_last))
+        def _merge():
+            k_buf[slot, last - step * P, off] = k_new_ref[0, 0]
+            v_buf[slot, last - step * P, off] = v_new_ref[0, 0]
+
+        kpos = step * (P * bs) + jax.lax.broadcasted_iota(jnp.int32, (G, P * bs), 1)
+        ok = kpos <= pos
+        if window > 0:
+            ok = ok & (((pos - kpos) < window) | is_global)
+        for h in range(Hkv):
+            q = q_ref[0, 0, pl.ds(h * G, G), :]  # (G, hd)
+            k = k_buf[slot, :, :, h, :].reshape(P * bs, -1)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale  # (G, P*bs)
+            if softcap > 0:
+                s = softcap * jnp.tanh(s / softcap)
+            s = jnp.where(ok, s, NEG_INF)
+            m_prev = m_ref[h]  # (G, 1)
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_cur)
+            p = jnp.exp(s - m_cur)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            v = v_buf[slot, :, :, h, :].reshape(P * bs, -1).astype(jnp.float32)
+            # HIGHEST: at the default precision Mosaic rounds f32 operands
+            # to bf16 on the MXU, which would round the probabilities
+            acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+                p, v, precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[h] = m_cur
+        slot_ref[0] = nxt
+        return carry
+
+    jax.lax.fori_loop(first_step(b), s_last + 1, step_body, 0)
+    for h in range(Hkv):
+        out = acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)
+        o_ref[0, 0, pl.ds(h * G, G), :] = out.astype(o_ref.dtype)
+
+    @pl.when(lands)
+    def _written():
+        for c in writes:
+            c.wait()
+
+
+def _decode_call(q, k_pages, n_blocks, scale, softcap, window, interpret):
+    """``pallas_call`` of the single-token kernel: grid ``(B,)``, the pool
+    left in HBM (aliased in and out), a row's q / new K/V / output as
+    whole-row blocks, ``P`` pages a step from the shapes."""
+    B, _, Hq, hd = q.shape
+    bs, Hkv = k_pages.shape[1], k_pages.shape[2]
+    if Hq % Hkv:
+        raise ValueError("GQA requires q heads to divide over kv heads")
+    G = Hq // Hkv
+    P = _pages_per_step(bs, n_blocks)
+    q_spec = pl.BlockSpec((1, 1, Hq, hd), lambda b, *_: (b, 0, 0, 0))
+    kv_spec = pl.BlockSpec((1, 1, Hkv, hd), lambda b, *_: (b, 0, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    item = k_pages.dtype.itemsize
+
+    def rows(n):  # rows of whole VMEM tiles: 8 f32 or 16 bf16 rows a tile
+        return -(-n // (32 // item)) * (32 // item)
+
+    block_bytes = 2 * rows(Hq) * hd * q.dtype.itemsize + 2 * rows(Hkv) * hd * item
+    scratch_bytes = (
+        4 * P * bs * rows(Hkv) * hd * item  # K and V, two steps deep
+        + 2 * P * bs * hd * 4  # one head's f32 values and their scores
+        + 4 * Hkv * -(-G // 8) * 8 * (hd + 2 * 128)  # f32 carries, whole tiles
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B,),
+        in_specs=[q_spec, pool_spec, pool_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, pool_spec, pool_spec],
+        scratch_shapes=[
+            pltpu.VMEM((2, P, bs, Hkv, hd), k_pages.dtype),
+            pltpu.VMEM((2, P, bs, Hkv, hd), k_pages.dtype),
+            pltpu.SemaphoreType.DMA((3, 2)),  # K fetch, V fetch, append
+            pltpu.SMEM((1,), jnp.int32),  # the buffer the next step reads
+            pltpu.VMEM((Hkv, G, hd), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _decode_kernel,
+        P=P,
+        n_blocks=n_blocks,
+        bs=bs,
+        G=G,
+        Hkv=Hkv,
+        scale=hd**-0.5 if scale is None else scale,
+        softcap=softcap,
+        window=window,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+            jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+        ],
+        input_output_aliases={4: 1, 5: 2},  # pools, counting the 3 prefetched
+        # the grid's default "arbitrary" order runs rows one after another,
+        # which the hand-off of buffers and prefetches between rows needs
+        compiler_params=_vmem_params(block_bytes, scratch_bytes),
+        interpret=interpret,
+    )
+
+
+def _pages_per_step(bs: int, n_blocks: int) -> int:
+    """Pages a loop step walks: about ``_KEYS_PER_STEP`` keys, never more
+    pages than the table holds (one whole page where a page alone is
+    that long, as in the contiguous layout's one page a row)."""
+    return max(1, min(n_blocks, _KEYS_PER_STEP // bs))
+
+
+def _slices_pages(Hkv: int, itemsize: int) -> bool:
+    """Whether Mosaic can slice one page out of the pool in HBM. XLA tiles
+    a packed (sub-32-bit) pool's kv-head dim by the power of two at or
+    above it, at least one 32-bit word and at most 8 rows deep, and Mosaic
+    slices only whole tiles (compiles for a described v5e: bf16 pools with
+    2, 4, 8, 16, 24 or 32 kv heads slice, 1, 3, 5, 6 or 12 do not). Pools
+    that do not take the page-walk kernel of the chunk path instead."""
+    if itemsize >= 4:
+        return True
+    tile = min(8, max(4 // itemsize, 1 << (Hkv - 1).bit_length()))
+    return Hkv % tile == 0
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "softcap", "window", "interpret"))
 def paged_attention(
     q: jax.Array,
@@ -437,6 +698,12 @@ def paged_attention(
         raise ValueError("pos must be a (B,) vector (broadcast scalars)")
     n_blocks = block_tables.shape[1]
     flags = jnp.asarray(is_global, jnp.int32).reshape(1)
+    if q.shape[1] == 1 and _slices_pages(k_pages.shape[2], k_pages.dtype.itemsize):
+        call = _decode_call(q, k_pages, n_blocks, scale, softcap, window, interpret)
+        return call(
+            block_tables, pos, flags, q, k_pages, v_pages,
+            k_new.astype(k_pages.dtype), v_new.astype(v_pages.dtype),
+        )
 
     def page_map(b, j, tables, pos, flags):
         return (tables[b, j], 0, 0, 0)

@@ -1556,6 +1556,12 @@ class InferenceEngine:
             out.update(chunk_uid=r.uid, chunk_real=max(0, lo + n - max(lo, pad)))
             ctx += n * lo + n * (n + 1) // 2
         out["ctx"] = ctx
+        if rows and live.tables is not None:
+            # the decode kernel walks the pages up to each row's query
+            # position (pos - 1 now), out of the table's full width
+            bs = self.kv_block_size
+            out["kv_pages_live"] = int(sum((live.pos[j] - 1) // bs + 1 for j in rows))
+            out["kv_pages_table"] = len(rows) * live.max_blocks
         return out
 
     def _ensure_blocks(
@@ -1903,6 +1909,9 @@ class InferenceEngine:
         if s.table is not None:
             s.table.free()
             live.tables[i, :] = TRASH_BLOCK
+        # a drained row still rides every decode step: at position 0 the
+        # decode kernel walks one trash page for it, not its old length
+        live.pos[i] = 0
         s.pending = []
         live.slots[i] = None
         live.next_tok[i] = 0

@@ -16,6 +16,7 @@ from conftest import reduced
 from repro.core import HAPSession
 from repro.core.hap import fixed_plan
 from repro.kernels import ops, ref
+from repro.kernels import paged_attention as pa
 from repro.kernels.paged_attention import paged_attention
 from repro.models import init_params
 from repro.serving import Request
@@ -37,21 +38,37 @@ def _case(key, B, C, Hq, Hkv, hd, bs, nb, N, dtype=jnp.float32):
     return q, kp, vp, kn, vn, jnp.asarray(blocks, jnp.int32)
 
 
-@pytest.mark.parametrize("B,C,Hq,Hkv,hd,bs,nb", [
-    (2, 1, 4, 2, 16, 8, 3),      # plain decode, GQA
-    (1, 8, 2, 2, 32, 4, 4),      # chunk append spanning pages, MHA
-    (3, 4, 4, 1, 16, 8, 2),      # MQA
-    (2, 5, 8, 4, 8, 4, 3),       # uneven chunk vs block size
-    (3, 1, 4, 4, 16, 8, 3),      # decode, MHA over several kv heads (G=1,
-    #                              DeepSeek-MoE's shape)
+# decode rows of a table several loop steps wide whose width is no
+# multiple of the pages a step walks: query positions on the last slot of
+# the first step, the first slot of the second, the table's last slot,
+# and the first and last slots of a page inside a step
+_STEPS_BS, _STEPS_NB = 16, 20
+_STEPS_POS = (127, 128, 319, 16, 31)
+
+
+@pytest.mark.parametrize("B,C,Hq,Hkv,hd,bs,nb,at", [
+    (2, 1, 4, 2, 16, 8, 3, None),      # plain decode, GQA
+    (1, 8, 2, 2, 32, 4, 4, None),      # chunk append spanning pages, MHA
+    (3, 4, 4, 1, 16, 8, 2, None),      # MQA
+    (2, 5, 8, 4, 8, 4, 3, None),       # uneven chunk vs block size
+    (3, 1, 4, 4, 16, 8, 3, None),      # decode, MHA over several kv heads
+    #                                    (G=1, DeepSeek-MoE's shape)
+    (5, 1, 4, 2, 16, _STEPS_BS, _STEPS_NB, _STEPS_POS),  # decode, steps
+    (2, 1, 32, 8, 128, 16, 3, (31, 16)),   # decode, Mixtral's GQA widths
+    (2, 1, 16, 16, 128, 16, 3, (47, 0)),   # decode, DeepSeek's G=1 widths
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_kernel_matches_ref(B, C, Hq, Hkv, hd, bs, nb, dtype):
+def test_paged_kernel_matches_ref(B, C, Hq, Hkv, hd, bs, nb, at, dtype):
     q, kp, vp, kn, vn, tables = _case(0, B, C, Hq, Hkv, hd, bs, nb,
                                       B * nb + 2, dtype)
-    # rows at distinct depths; every write range stays inside the table
-    pos = jnp.asarray([(3 + 5 * i) % (nb * bs - C) for i in range(B)],
-                      jnp.int32)
+    if at is None:
+        # rows at distinct depths; every write range stays inside the table
+        at = [(3 + 5 * i) % (nb * bs - C) for i in range(B)]
+    if nb == _STEPS_NB:
+        P = pa._pages_per_step(bs, nb)
+        assert 1 < P < nb and nb % P, "the case must span ragged loop steps"
+        assert at[0] == P * bs - 1 and at[1] == P * bs
+    pos = jnp.asarray(at, jnp.int32)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     out_r, k_r, v_r = ref.paged_attention_ref(q, kp, vp, tables, kn, vn, pos,
                                               scale=hd ** -0.5)
@@ -79,6 +96,32 @@ def test_paged_kernel_masks(window, is_global, softcap):
         scale=16 ** -0.5, softcap=softcap, window=window, interpret=True)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("window,is_global,softcap", [
+    (6, False, 0.0), (6, True, 0.0), (0, True, 25.0), (300, False, 25.0),
+])
+def test_paged_decode_kernel_masks(window, is_global, softcap):
+    """Single-token decode over tables several loop steps wide: a sliding
+    window that leaves whole steps behind a row, softcap, and the layer
+    flag traced."""
+    q, kp, vp, kn, vn, tables = _case(19, 3, 1, 4, 2, 16, _STEPS_BS, _STEPS_NB,
+                                      3 * _STEPS_NB + 1)
+    pos = jnp.asarray([300, 257, 5], jnp.int32)
+
+    @jax.jit
+    def run(flag):
+        return paged_attention(q, kp, vp, tables, kn, vn, pos, flag,
+                               scale=16 ** -0.5, softcap=softcap, window=window,
+                               interpret=True)
+
+    out_r, k_r, _ = ref.paged_attention_ref(
+        q, kp, vp, tables, kn, vn, pos, is_global,
+        scale=16 ** -0.5, softcap=softcap, window=window)
+    out_p, k_p, _ = run(jnp.asarray(is_global))
+    np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(np.asarray(k_p)[1:], np.asarray(k_r)[1:])
 
 
 def test_paged_kernel_traced_is_global():
@@ -117,6 +160,28 @@ def test_paged_kernel_drained_row_leaves_live_pages_alone():
     # live pages of row 0 changed only at its write slot (17 -> block 3)
     np.testing.assert_array_equal(np.asarray(k_p)[1], np.asarray(kp)[1])
     assert not np.array_equal(np.asarray(k_p)[3], np.asarray(kp)[3])
+
+    # a drained row between live rows of tables several loop steps wide,
+    # its stale position deep in the table: it walks trash pages only
+    q, kp, vp, kn, vn, tables = _case(14, 3, 1, 4, 2, 16, _STEPS_BS, _STEPS_NB,
+                                      3 * _STEPS_NB + 1)
+    tables = tables.at[1].set(0)
+    pos = jnp.asarray([300, 290, 257], jnp.int32)
+    out_r, k_r, v_r = ref.paged_attention_ref(q, kp, vp, tables, kn, vn, pos,
+                                              scale=2 ** -0.5)
+    out_p, k_p, v_p = paged_attention(q, kp, vp, tables, kn, vn, pos,
+                                      scale=2 ** -0.5, interpret=True)
+    assert np.isfinite(np.asarray(out_p)).all()
+    np.testing.assert_allclose(np.asarray(out_p)[0::2], np.asarray(out_r)[0::2],
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(np.asarray(k_p)[1:], np.asarray(k_r)[1:])
+    np.testing.assert_array_equal(np.asarray(v_p)[1:], np.asarray(v_r)[1:])
+    # every live page keeps its content but the two write slots
+    live = np.asarray(tables)[0::2].ravel()
+    written = {int(tables[0, 300 // _STEPS_BS]), int(tables[2, 257 // _STEPS_BS])}
+    for page in live:
+        if int(page) not in written:
+            np.testing.assert_array_equal(np.asarray(k_p)[page], np.asarray(kp)[page])
 
 
 @pytest.mark.parametrize("layout", ["contiguous_scalar", "contiguous_rows",

@@ -24,6 +24,7 @@ from conftest import reduced
 from repro.core import HAPSession
 from repro.core.hap import fixed_plan
 from repro.kernels import ops, ref
+from repro.kernels import paged_attention as pa
 from repro.kernels.paged_attention import paged_attention, prefix_paged_attention
 from repro.models import init_params
 from repro.serving import Request
@@ -269,6 +270,21 @@ def test_prefix_kernel_masks(window, is_global, softcap):
                                atol=2e-5, rtol=2e-5)
 
 
+def _page_walk(q, kp, vp, tables, kn, vn, pos):
+    """``paged_attention``'s page-walk kernel (its chunk path) at C=1."""
+    B, nb = tables.shape
+
+    def page_map(b, j, t, *_):
+        return (t[b, j], 0, 0, 0)
+
+    def row_map(b, j, *_):
+        return (b, 0, 0, 0)
+
+    call = pa._paged_call(pa._paged_kernel, (page_map, row_map), 3, (B, nb), nb, (),
+                          q, kp, vp, kn, vn, q.shape[-1] ** -0.5, 0.0, 0, True)
+    return call(tables, pos, jnp.ones((1,), jnp.int32), q, kp, vp, kn, vn)[0]
+
+
 def test_ops_prefix_dispatch_and_identity_groups():
     """ops.decode_attention routes prefix_groups to the prefix kernels
     (both backends) and an identity grouping reproduces the plain path
@@ -294,7 +310,17 @@ def test_ops_prefix_dispatch_and_identity_groups():
                                             block_tables=tables,
                                             scale=16 ** -0.5, backend=backend)
         assert ops.DISPATCH_COUNTS.get(key, 0) == 2
-        np.testing.assert_array_equal(np.asarray(o_i), np.asarray(o_plain))
+        if backend == "pallas":
+            # the prefix kernel is the chunk path's page walk read through
+            # the group indirection: with identity groups it is that walk
+            # bit for bit; plain single-token decode has its own kernel,
+            # which sums the same terms in another order
+            np.testing.assert_array_equal(
+                np.asarray(o_i), np.asarray(_page_walk(q, kp, vp, tables, kn, vn, pos)))
+            np.testing.assert_allclose(np.asarray(o_i), np.asarray(o_plain),
+                                       atol=2e-5, rtol=2e-5)
+        else:
+            np.testing.assert_array_equal(np.asarray(o_i), np.asarray(o_plain))
         np.testing.assert_allclose(np.asarray(o_g), np.asarray(o_plain),
                                    atol=2e-5, rtol=2e-5)
     with pytest.raises(ValueError, match="paged cache"):

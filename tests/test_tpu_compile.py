@@ -42,13 +42,13 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _paged(B, C):
+def _paged(B, C, hq=H, hkv=H, width=WIDTH, pool=POOL):
     def fn(q, kp, vp, tables, kn, vn, pos):
         return paged_attention(q, kp, vp, tables, kn, vn, pos, interpret=False)
 
-    return fn, [((B, C, H, HD), jnp.bfloat16), ((POOL, BS, H, HD), jnp.bfloat16),
-                ((POOL, BS, H, HD), jnp.bfloat16), ((B, WIDTH), jnp.int32),
-                ((B, C, H, HD), jnp.bfloat16), ((B, C, H, HD), jnp.bfloat16),
+    return fn, [((B, C, hq, HD), jnp.bfloat16), ((pool, BS, hkv, HD), jnp.bfloat16),
+                ((pool, BS, hkv, HD), jnp.bfloat16), ((B, width), jnp.int32),
+                ((B, C, hkv, HD), jnp.bfloat16), ((B, C, hkv, HD), jnp.bfloat16),
                 ((B,), jnp.int32)]
 
 
@@ -89,6 +89,11 @@ def _dequant():
 CASES = {
     "paged_decode": lambda: _paged(SLOTS, 1),
     "paged_chunk": lambda: _paged(1, CHUNK),
+    # single-token decode at the benchmark cells' widths: 64 rows, tables
+    # 160 pages wide (2560 positions), Mixtral-8x7B's 32 q / 8 kv heads
+    # and DeepSeek-MoE-16B's 16 / 16
+    "paged_decode_mixtral": lambda: _paged(64, 1, hq=32, hkv=8, width=160, pool=10241),
+    "paged_decode_deepseek": lambda: _paged(64, 1, width=160, pool=10241),
     "prefix_paged_decode": _prefix,
     "flash_s512": _flash,
     "gmm_c8": lambda: _gmm(8),

@@ -151,6 +151,34 @@ def test_step_attributes_describe_the_work(served):
     assert real == {uid: len(p) for uid, (p, _) in enumerate(PROMPTS)}
 
 
+def test_decode_steps_count_the_pages_they_walk(served, moe_setup, tmp_path):
+    """Decode-carrying steps carry the pages the decode kernel walks (each
+    decoding row's up to its query position) against the rows' table
+    width; prefill-only steps carry neither."""
+    for s in _by_name(served.spans, "engine.step"):
+        a = s.attrs
+        if a["rows"]:
+            assert a["rows"] <= a["kv_pages_live"] <= a["kv_pages_table"]
+            assert a["kv_pages_table"] % a["rows"] == 0
+        else:
+            assert "kv_pages_live" not in a and "kv_pages_table" not in a
+    # one row at known positions: a 12-token prompt padded to 16, then
+    # decode writes and attends positions 16..20, 4 to a page
+    cfg, params = moe_setup
+    eng = _engine(cfg, params, max_batch=1)
+    eng.submit(Request(prompt=list(range(1, 13)), max_new_tokens=6))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.serve_continuous()
+    finally:
+        jax.profiler.stop_trace()
+    steps = [s.attrs for s in _by_name(eng.trace.spans, "engine.step")
+             if s.attrs["kind"] == "decode"]
+    assert [a["kv_pages_live"] for a in steps] == [p // 4 + 1 for p in range(16, 21)]
+    widths = {a["kv_pages_table"] for a in steps}
+    assert len(widths) == 1 and widths.pop() >= 6
+
+
 def test_step_spans_reach_the_xplane(served):
     from jax.profiler import ProfileData
 
