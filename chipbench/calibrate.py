@@ -62,7 +62,7 @@ def main(argv=None, require_tpu: bool = True, root=run.ROOT) -> int:
         if engine is not None:
             engine.params = None
             gc.collect()
-        params = weights.make(m, seed, sharding)
+        params = weights.make(m, seed, sharding, cell.arch)
         if engine is None:
             engine, wloop = run.prepare(cell, params)
         else:
@@ -76,7 +76,7 @@ def main(argv=None, require_tpu: bool = True, root=run.ROOT) -> int:
         gc.collect()
         picked = check.sample(win.rec.reqs.values(), seed, limits["sample_tokens"],
                               limits["sample_most"])
-        g = [check.gaps(engine.params, m, r, control=True) for r in picked]
+        g = [check.gaps(engine.params, m, r, control=True, arch=cell.arch) for r in picked]
         s = check.statistics([x["served"] for x in g])
         c = check.statistics([x["control"] for x in g])
         served.append(s)

@@ -95,7 +95,7 @@ def sequence(r) -> tuple:
     return seq, rows
 
 
-def gaps(params, m: Dict, r, control: bool = False) -> Dict[str, np.ndarray]:
+def gaps(params, m: Dict, r, control: bool = False, arch=None) -> Dict[str, np.ndarray]:
     """Per served token: the reference's best logit minus its logit of
     the served token (``served``); with ``control``, also of the token
     the float8 reference puts first (``control``); and the reference's
@@ -105,13 +105,13 @@ def gaps(params, m: Dict, r, control: bool = False) -> Dict[str, np.ndarray]:
     on the host."""
     seq, rows = sequence(r)
     n = len(rows)
-    ref = reference.logits_padded(params, m, seq, rows, "f32")
+    ref = reference.logits_padded(params, m, seq, rows, "f32", arch)
     toks = np.zeros(ref.shape[0], np.int32)
     toks[:n] = r.tokens
     served, margin = _served_gaps(ref, toks)
     out = {"served": np.asarray(served)[:n], "margin": np.asarray(margin)[:n]}
     if control:
-        low = reference.logits_padded(params, m, seq, rows, "fp8")
+        low = reference.logits_padded(params, m, seq, rows, "fp8", arch)
         out["control"] = np.asarray(_picked_gaps(ref, low))[:n]
     return out
 

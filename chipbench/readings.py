@@ -33,6 +33,7 @@ class Run:
     trace: Optional[tr.Trace] = None
     offset: float = 0.0  # trace clock minus perf_counter
     traced: Tuple[float, float] = (0.0, 0.0)  # perf_counter span of the trace
+    arch: Any = None  # the architecture module (spec.py); None: spec.DEFAULT_ARCH
 
     def steps(self) -> List:
         lo, hi = self.window
@@ -122,7 +123,7 @@ def _flops(run: Run, s) -> float:
         lo = hi - s.chunk_real  # the real tokens end the padded chunk
         # only a prompt's last chunk samples a token (never a fused one)
         sampled = st.kind == "chunk" and hi >= run.rec.reqs[st.chunk_uid].padded
-    return wc.step_flops(run.model, (lo, hi), st.decode_ctx, sampled)
+    return wc.step_flops(run.model, (lo, hi), st.decode_ctx, sampled, run.arch)
 
 
 def mfu(run: Run) -> Optional[float]:
@@ -176,6 +177,6 @@ def paged_attn_roofline(run: Run) -> Optional[float]:
     """Least time of decode-only steps' paged attention over the paged
     kernel's device time inside those steps, %."""
     steps = [s for s in run.traced_steps() if s.step.kind == "decode"]
-    least = sum(wc.least_time(*wc.paged_attn_work(run.model, s.step.decode_ctx),
+    least = sum(wc.least_time(*wc.paged_attn_work(run.model, s.step.decode_ctx, run.arch),
                               run.peaks) for s in steps)
     return _kernel_share(run, PAGED_KERNEL, steps, least)

@@ -3,16 +3,15 @@
 A copy of the repository's ``models/reference.py`` at the time the
 benchmark was written, kept here so that the yardstick does not move
 with the program; it imports nothing of the program. It takes the
-configuration as the dict of a config file's ``model`` section and the
-weights as ``weights.make`` lays them out.
+configuration as the dict of a config file's ``model`` section, the
+weights as ``weights.make`` lays them out, and the architecture module
+(``chipbench/arch/<name>.py``, ``spec.py``) whose ``layers`` runs the
+layer stack between the embedding and the head here.
 
-Causal attention over the whole sequence, every routed expert computed
-densely for every token and weighted by its renormalized top-k gate
-(zero off the top-k), shared experts added, RMS norms, rotary positions
-on the two halves of each head. Matrix products run in float32 at
-``default_matmul_precision("highest")``. To fit one chip beside nothing
-else, it runs one layer at a time and, inside a layer, one expert at a
-time, slicing and upcasting only that expert's bfloat16 weights.
+Matrix products run in float32 at ``default_matmul_precision("highest")``.
+To fit one chip beside nothing else, the layer stacks run one layer at a
+time, slicing and upcasting only the weights each product needs; the
+helpers below are theirs.
 
 ``precision="fp8"`` is the control: every matrix product takes its
 operands rounded to float8 e4m3 (absmax-scaled per row of activations and
@@ -28,6 +27,8 @@ from typing import Any, Dict, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import spec
 
 _F32 = jnp.float32
 _FP8_MAX = 448.0  # largest finite float8_e4m3fn
@@ -74,58 +75,6 @@ def _at(w, l):
     return jax.lax.dynamic_index_in_dim(w, l, 0, keepdims=False).astype(_F32)
 
 
-def _attention(m, a, l, h, fp8):
-    S = h.shape[0]
-    Hq, Hkv, hd = m["num_heads"], m["num_kv_heads"], m["head_dim"]
-    q = _rope(_mm(h, _at(a["wq"], l), fp8).reshape(S, Hq, hd), m["rope_theta"])
-    k = _rope(_mm(h, _at(a["wk"], l), fp8).reshape(S, Hkv, hd), m["rope_theta"])
-    v = _mm(h, _at(a["wv"], l), fp8).reshape(S, Hkv, hd)
-    kv_head = jnp.arange(Hq) // (Hq // Hkv)
-    k, v = k[:, kv_head], v[:, kv_head]
-    if fp8:
-        q, k, v = _fp8(q, -1), _fp8(k, -1), _fp8(v, 0)
-    s = jnp.einsum("qhd,khd->hqk", q, k) * hd ** -0.5
-    causal = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
-    p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
-    if fp8:
-        p = _fp8(p, -1)
-    o = jnp.einsum("hqk,khd->qhd", p, v).reshape(S, Hq * hd)
-    return _mm(o, _at(a["wo"], l), fp8)
-
-
-def _moe(m, mp, l, h, fp8):
-    probs = jax.nn.softmax(_mm(h, _at(mp["router"], l), fp8), axis=-1)
-    top, idx = jax.lax.top_k(probs, m["top_k"])
-    top = top / jnp.sum(top, -1, keepdims=True)
-    gates = jnp.zeros_like(probs).at[jnp.arange(h.shape[0])[:, None], idx].set(top)
-
-    def expert(y, e):
-        def w(name):
-            leaf = mp[name]
-            blk = jax.lax.dynamic_slice(
-                leaf, (l, e, 0, 0), (1, 1) + leaf.shape[2:])
-            return blk[0, 0].astype(_F32)
-
-        g = _act(m, _mm(h, w("wi_gate"), fp8)) * _mm(h, w("wi_up"), fp8)
-        return y + gates[:, e, None] * _mm(g, w("wo"), fp8), None
-
-    y, _ = jax.lax.scan(expert, jnp.zeros_like(h), jnp.arange(m["n_routed_experts"]))
-    if m["n_shared_experts"]:
-        g = (_act(m, _mm(h, _at(mp["shared_wi_gate"], l), fp8))
-             * _mm(h, _at(mp["shared_wi_up"], l), fp8))
-        y = y + _mm(g, _at(mp["shared_wo"], l), fp8)
-    return y
-
-
-@functools.partial(jax.jit, static_argnums=(0, 4))
-def _layer(mkey, layers, l, x, fp8):
-    m = json.loads(mkey)
-    eps = m["norm_eps"]
-    x = x + _attention(m, layers["attn"], l, _rms(x, _at(layers["ln1"], l), eps), fp8)
-    h = _rms(x, _at(layers["ln2"], l), eps)
-    return x + _moe(m, layers["moe"], l, h, fp8)
-
-
 @functools.partial(jax.jit, static_argnums=(0, 4))
 def _head(mkey, params, x, rows, fp8):
     m = json.loads(mkey)
@@ -136,14 +85,14 @@ def _head(mkey, params, x, rows, fp8):
 
 
 def logits_at(params, m: Dict[str, Any], tokens: Sequence[int],
-              rows: Sequence[int], precision: str = "f32") -> jax.Array:
+              rows: Sequence[int], precision: str = "f32", arch=None) -> jax.Array:
     """Logits ``(len(rows), vocab)`` at positions ``rows`` of the 1-D
     token sequence ``tokens``."""
-    return logits_padded(params, m, tokens, rows, precision)[: len(rows)]
+    return logits_padded(params, m, tokens, rows, precision, arch)[: len(rows)]
 
 
 def logits_padded(params, m: Dict[str, Any], tokens: Sequence[int],
-                  rows: Sequence[int], precision: str = "f32") -> jax.Array:
+                  rows: Sequence[int], precision: str = "f32", arch=None) -> jax.Array:
     """As ``logits_at``, with the rows padded at the end to a multiple of
     ``_ROW_BLOCK`` (the last row repeated): the first ``len(rows)`` are
     the logits asked for."""
@@ -161,11 +110,11 @@ def logits_padded(params, m: Dict[str, Any], tokens: Sequence[int],
     want[: len(rows)] = rows
     with jax.default_matmul_precision("highest"):
         x = params["embed"][jnp.asarray(toks)].astype(_F32)
-        for l in range(m["num_layers"]):
-            x = _layer(mkey, params["layers"], jnp.int32(l), x, fp8)
+        x = spec.resolve(arch).layers(m, params, x, fp8)
         return _head(mkey, params, x, jnp.asarray(want), fp8)
 
 
-def reference_logits(params, m: Dict[str, Any], tokens: Sequence[int]) -> jax.Array:
+def reference_logits(params, m: Dict[str, Any], tokens: Sequence[int],
+                     arch=None) -> jax.Array:
     """Float32 logits ``(S, vocab)`` at every position."""
-    return logits_at(params, m, tokens, range(len(tokens)))
+    return logits_at(params, m, tokens, range(len(tokens)), arch=arch)

@@ -110,7 +110,7 @@ def build_engine(cell: spec.Cell, params):
         cfg, plan["chip"], cell.chips,
         source=fixed_plan(plan["attn"], plan["experts"]),
         fallback="", prompt_bucket=eng["prompt_bucket"], gen_bucket=eng["gen_bucket"])
-    pool = spec.pool_blocks(cell.config, cell.traffic)
+    pool = spec.pool_blocks(cell.config, cell.traffic, cell.arch)
     return session.engine(params, max_batch=cell.traffic["slots"], kv_block_size=eng["kv_block_size"],
                           kv_blocks=pool), pool
 
@@ -287,7 +287,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, device,
 
     m, tmix = cell.config["model"], cell.traffic
     sharding = SingleDeviceSharding(device)
-    params = weights.make(m, seed, sharding)
+    params = weights.make(m, seed, sharding, cell.arch)
     jax.block_until_ready(params)
     log(f"weights made {time.perf_counter() - T_START:.3f} s after start")
     engine, wloop = prepare(cell, params)
@@ -300,7 +300,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, device,
     peak = (device.memory_stats() or {}).get("peak_bytes_in_use", 0)
 
     run = rd.Run(model=m, traffic=tmix, peaks=peaks, rec=rec, window=(t0, t1),
-                 due_until=win.end)
+                 due_until=win.end, arch=cell.arch)
     if trace:
         tr = devtrace.load(devtrace.find_xplane(win.trace_dir))
         shutil.rmtree(win.trace_dir, ignore_errors=True)
@@ -350,6 +350,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, device,
         values = {"ttft_p95_ms": rd.ms(rd.pctl(ttft, 95)),
                   "tbt_p95_ms": rd.ms(rd.pctl(tbt, 95)),
                   "output_tok_s": rd.output_tokens(run) / (t1 - t0),
+                  "prompt_tok_s": rd.prompt_tokens(run) / (t1 - t0),
                   "setup_s": setup_s}
         for mdef in cell.end_to_end:
             if values.get(mdef["name"]) is not None:
@@ -367,13 +368,14 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, device,
     picked = check.sample(rec.reqs.values(), seed, limits["sample_tokens"],
                           limits["sample_most"])
     t_ref = time.perf_counter()
-    params = weights.make(m, seed, sharding)
-    stats = check.statistics([check.gaps(params, m, r)["served"] for r in picked])
+    params = weights.make(m, seed, sharding, cell.arch)
+    stats = check.statistics([check.gaps(params, m, r, arch=cell.arch)["served"]
+                              for r in picked])
     log(f"reference over {len(picked)} requests ({sum(len(r.tokens) for r in picked)} "
         f"served tokens, longest {picked[0].padded + len(picked[0].tokens) if picked else 0}"
         f" positions) in {time.perf_counter() - t_ref:.1f} s; served-token gaps {stats}")
     quiet = sorted(k for k, n in dispatch.items() if n and ".ref" in k)
-    kernels = [f for f in ("decode", "gmm")
+    kernels = [f for f in cell.arch.KERNELS
                if not any(k.startswith(f + ".pallas") and n for k, n in dispatch.items())]
     checks = {
         check.COMPARED: [stats[check.COMPARED], limits["limit"]],

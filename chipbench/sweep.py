@@ -50,7 +50,8 @@ def main(argv=None, require_tpu: bool = True, root=run.ROOT) -> int:
     if require_tpu and device.platform != "tpu":
         print(f"sweep: no TPU: JAX reports {device.platform}", file=sys.stderr)
         return 1
-    params = weights.make(cell.config["model"], args.seed, SingleDeviceSharding(device))
+    params = weights.make(cell.config["model"], args.seed, SingleDeviceSharding(device),
+                          cell.arch)
     engine, wloop = run.prepare(cell, params)
     del params
     for rate in args.rates:
@@ -58,7 +59,7 @@ def main(argv=None, require_tpu: bool = True, root=run.ROOT) -> int:
         win = run.serve(cell, engine, wloop.key, args.seed, args.seconds, False,
                         time.perf_counter())
         r = rd.Run(model=cell.config["model"], traffic=cell.traffic, peaks={},
-                   rec=win.rec, window=(win.t0, win.t1), due_until=win.end)
+                   rec=win.rec, window=(win.t0, win.t1), due_until=win.end, arch=cell.arch)
         due = sorted(rd.due_in_window(r), key=lambda q: q.due)
         ttft = rd.ttfts(r)
         by_due = [t for _, t in sorted(zip([q.due for q in due], ttft))]

@@ -19,13 +19,19 @@ TINY_LIMIT = 0.007
 sys.path.insert(0, str(BENCH))
 
 
-def tiny_checkout(tmp: Path, cells) -> Path:
+def tiny_checkout(tmp: Path, cells, arch=None) -> Path:
     """A checkout holding the benchmark as it is, the program beside it,
     and, as new files only, the tiny config, the given tiny traffic mixes
-    and their limits, with a BENCHMARK.json that names those cells."""
+    and their limits, with a BENCHMARK.json that names those cells. With
+    ``arch``, the config names that architecture, and
+    ``chipbench/arch/<arch>.py`` is added as a copy of ``moe_gqa.py``."""
     shutil.copytree(BENCH, tmp / "chipbench", ignore=shutil.ignore_patterns("tests"))
     os.symlink(REPO / "src", tmp / "src")
-    shutil.copy(DATA / "tiny.json", tmp / "chipbench" / "configs" / "tiny.json")
+    tiny = json.loads((DATA / "tiny.json").read_text())
+    if arch is not None:
+        shutil.copy(BENCH / "arch" / "moe_gqa.py", tmp / "chipbench" / "arch" / f"{arch}.py")
+        tiny["arch"] = arch
+    (tmp / "chipbench" / "configs" / "tiny.json").write_text(json.dumps(tiny))
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     bench["configs"].append({"name": "tiny", "source": "https://arxiv.org/abs/2401.06066",
                              "file": "chipbench/configs/tiny.json", "reduced": [],

@@ -7,9 +7,10 @@ import numpy as np
 import pytest
 from conftest import BENCH
 
+import spec
 import traffic as tg
 
-MIXES = ["chat", "decode"]
+MIXES = ["chat", "decode", "longprompt"]
 
 
 def _mix(name):
@@ -57,3 +58,20 @@ def test_strata_follow_the_distribution():
     assert np.median(x) == 100
     u = tg.stratified({"dist": "uniform", "min": 16, "max": 64}, 49)
     assert u.min() >= 16 and u.max() <= 64 and np.median(u) == 40
+
+
+def test_longprompt_holds_long_prompts_in_one_table_and_outlasts_the_window():
+    mix = _mix("longprompt")
+    seconds = 51  # BENCHMARK.json's run_seconds
+    reqs = tg.generate(mix, seconds, 2**31 + 3, 102400)
+    assert all(1024 <= len(r.prompt) <= 3584 and 16 <= r.max_new <= 64 for r in reqs)
+    # an offline mix warms and runs at its widest table alone
+    assert mix["loop"] == "offline" and spec.widths(mix, 512)[-1] == 4096
+    # the pool is under the worst case (32 rows x 4096 / 16 = 8,192 blocks):
+    # admission reserves each request's own need
+    conf = json.loads((BENCH / "configs" / "deepseek-moe-16b.1chip.json").read_text())
+    assert spec.pool_blocks(conf, mix, spec.load_arch(BENCH.parent, conf["arch"])) == 5722
+    # the requests queued behind the first wave last the whole window even
+    # at five times the prefill rate measured on the chip (0.8 requests/s)
+    behind = reqs[mix["slots"]:]
+    assert len(behind) / (5 * 0.8) >= seconds
