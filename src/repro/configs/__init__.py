@@ -21,6 +21,8 @@ _MODULES = (
     "mixtral_8x7b",
     "qwen15_moe_a2_7b",
     "qwen2_57b_a14b",
+    # multi-head latent attention
+    "deepseek_v2_lite",
 )
 
 _loaded = False

@@ -59,6 +59,30 @@ class ModelConfig:
     final_logit_softcap: float = 0.0
     query_pre_attn_scalar: float = 0.0  # 0 => 1/sqrt(head_dim)
 
+    # -- multi-head latent attention (DeepSeek-V2, arXiv:2405.04434) ---------
+    # kv_lora_rank > 0 selects MLA: keys and values of every head come from
+    # one cached latent row a token — the kv_lora_rank-wide compression,
+    # RMS-normed, then a qk_rope_head_dim-wide roped key that all heads
+    # share; queries are qk_nope_head_dim + qk_rope_head_dim wide (head_dim),
+    # values v_head_dim. 0 => standard (GQA) attention.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rotary scaling (arXiv:2309.00071); yarn_factor 0 => plain rope.
+    # Inverse frequencies whose wavelength fits yarn_beta_fast rotations or
+    # more into yarn_original_max_position stay as they are, those under
+    # yarn_beta_slow are divided by the factor, linear between; cos/sin
+    # scale by mscale(yarn_mscale) / mscale(yarn_mscale_all_dim) and the
+    # softmax scale by mscale(yarn_mscale_all_dim)^2, with
+    # mscale(m) = 0.1 * m * ln(factor) + 1.
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+
     # -- FFN variants --------------------------------------------------------
     activation: str = "silu"         # silu (SwiGLU) | gelu (GeGLU) | gelu_plain
 
@@ -70,8 +94,11 @@ class ModelConfig:
     shared_d_ff: int = 0             # shared-expert intermediate; 0 => moe_d_ff
     capacity_factor: float = 1.25
     router_aux_loss_coef: float = 0.01
-    # dense FFN layers interleaved with MoE layers (deepseek uses 1 dense
-    # first layer; we keep stacks uniform => model it as shared experts).
+    # leading dense layers: the first k layers have a dense FFN of d_ff in
+    # place of the MoE layer (DeepSeek's first_k_dense_replace); their
+    # weights live apart from the scanned MoE stack, one tree a layer
+    # (``dense_layers``)
+    first_k_dense_replace: int = 0
 
     # -- SSM (mamba1) --------------------------------------------------------
     ssm_state: int = 0               # N (state size per channel)
@@ -98,6 +125,9 @@ class ModelConfig:
 
     # ------------------------------------------------------------------
     def __post_init__(self):
+        if self.head_dim == 0 and self.kv_lora_rank:
+            object.__setattr__(self, "head_dim",
+                               self.qk_nope_head_dim + self.qk_rope_head_dim)
         if self.head_dim == 0 and self.block_type in ("attention", "hybrid"):
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
         if self.ffn_type == "moe":
@@ -124,6 +154,22 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.ffn_type == "moe"
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def num_dense_layers(self) -> int:
+        """Leading dense-FFN layers (MoE models); the rest are MoE."""
+        return self.first_k_dense_replace if self.is_moe else 0
+
+    @property
+    def latent_width(self) -> int:
+        """Lanes of one MLA latent cache row: kv_lora_rank +
+        qk_rope_head_dim, rounded up to whole 128-lane tiles (the TPU's
+        HBM layout holds a row that wide whatever its declared width)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
 
     @property
     def is_encoder_only(self) -> bool:
@@ -155,7 +201,14 @@ class ModelConfig:
         counts["embed"] = self.vocab_size * d
         counts["lm_head"] = 0 if self.tie_embeddings else self.vocab_size * d
         attn = 0
-        if self.has_attention:
+        if self.is_mla:
+            r, dr, dv = self.kv_lora_rank, self.qk_rope_head_dim, self.v_head_dim
+            h = self.num_heads
+            attn += d * h * hd                       # q
+            attn += d * (r + dr) + r                 # kv_a, kv_norm
+            attn += r * h * (self.qk_nope_head_dim + dv)  # kv_b
+            attn += h * dv * d                       # o
+        elif self.has_attention:
             attn += d * self.num_heads * hd          # q
             attn += 2 * d * self.num_kv_heads * hd   # k, v
             attn += self.num_heads * hd * d          # o
@@ -183,8 +236,15 @@ class ModelConfig:
         counts["norms_per_layer"] = (4 if self.use_post_norm else 2) * d
         counts["per_layer"] = (counts["attn_per_layer"] + counts["ffn_per_layer"]
                                + counts["norms_per_layer"])
+        # the leading dense layers' FFN, and every layer's FFN summed
+        k = self.num_dense_layers
+        counts["dense_ffn_per_layer"] = mult * d * self.d_ff if k else 0
+        counts["ffn_total"] = ((self.num_layers - k) * counts["ffn_per_layer"]
+                               + k * counts["dense_ffn_per_layer"])
         counts["total"] = (counts["embed"] + counts["lm_head"] + d
-                           + self.num_layers * counts["per_layer"])
+                           + self.num_layers * (counts["attn_per_layer"]
+                                                + counts["norms_per_layer"])
+                           + counts["ffn_total"])
         return counts
 
     def total_params(self) -> int:
@@ -201,8 +261,11 @@ class ModelConfig:
         active_ffn = (self.top_k * mult * d * self.moe_d_ff
                       + self.n_shared_experts * mult * d * self.shared_d_ff
                       + d * self.n_routed_experts)
-        per_layer = c["attn_per_layer"] + active_ffn + c["norms_per_layer"]
-        return c["embed"] + c["lm_head"] + d + self.num_layers * per_layer
+        k = self.num_dense_layers
+        per_layer = c["attn_per_layer"] + c["norms_per_layer"]
+        return (c["embed"] + c["lm_head"] + d + self.num_layers * per_layer
+                + (self.num_layers - k) * active_ffn
+                + k * c["dense_ffn_per_layer"])
 
     # -- reduced variant for CPU smoke tests ---------------------------------
     def reduced(self) -> "ModelConfig":
@@ -228,8 +291,18 @@ class ModelConfig:
             frontend_dim=min(self.frontend_dim, 128) if self.frontend_dim else 0,
             sliding_window=min(self.sliding_window, 16) if self.sliding_window else 0,
         )
+        if self.is_mla:
+            kw.update(
+                kv_lora_rank=min(self.kv_lora_rank, 64),
+                qk_nope_head_dim=min(self.qk_nope_head_dim, 32),
+                qk_rope_head_dim=min(self.qk_rope_head_dim, 16),
+                v_head_dim=min(self.v_head_dim, 32),
+                head_dim=(min(self.qk_nope_head_dim, 32)
+                          + min(self.qk_rope_head_dim, 16)),
+            )
         if self.is_moe:
             kw.update(
+                first_k_dense_replace=min(self.first_k_dense_replace, 1),
                 n_routed_experts=min(self.n_routed_experts, 4),
                 n_shared_experts=min(self.n_shared_experts, 1),
                 top_k=min(self.top_k, 2),

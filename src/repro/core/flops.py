@@ -43,12 +43,39 @@ class Workload:
 # ---------------------------------------------------------------------------
 # attention module
 # ---------------------------------------------------------------------------
+def _mla_attn_flops(cfg: ModelConfig, T: int, ctx: float, phase: str) -> float:
+    """Multi-head latent attention: the q, latent (kv_a) and output
+    projections per token; prefill decompresses every context row through
+    W_kvb and attends in the head space (dn + dr keys, dv values), decode
+    folds W_UK into the query and W_UV after the sum and attends in the
+    latent space (r + dr scores, r values, one shared row a position)."""
+    d, H = cfg.d_model, cfg.num_heads
+    r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                     cfg.v_head_dim)
+    proj = 2.0 * T * d * (H * (dn + dr) + r + dr + H * dv)
+    if phase == "prefill":
+        kv_b = 2.0 * T * r * H * (dn + dv)
+        return proj + kv_b + 2.0 * T * ctx * H * (dn + dr + dv)
+    absorb = 2.0 * T * H * r * (dn + dv)
+    return proj + absorb + 2.0 * T * ctx * H * (r + dr + r)
+
+
+def kv_row_bytes(cfg: ModelConfig, dtype_bytes: int = 2) -> float:
+    """KV cache bytes of one token in one layer: K and V of every kv head,
+    or MLA's one latent row (r + dr values, key and value at once)."""
+    if cfg.is_mla:
+        return (cfg.kv_lora_rank + cfg.qk_rope_head_dim) * dtype_bytes
+    return 2 * cfg.num_kv_heads * cfg.head_dim * dtype_bytes
+
+
 def attn_flops(cfg: ModelConfig, w: Workload, phase: str) -> float:
     """Global FLOPs of one Attention-module instance (one layer)."""
     T = w.tokens(phase)
     d = cfg.d_model
     f = 0.0
-    if cfg.has_attention:
+    if cfg.is_mla:
+        f += _mla_attn_flops(cfg, T, w.ctx(phase), phase)
+    elif cfg.has_attention:
         hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         proj = 2.0 * T * d * (2 * hq * hd + 2 * hkv * hd)
         ctx = w.ctx(phase)
@@ -86,7 +113,7 @@ def kv_bytes_per_layer(cfg: ModelConfig, w: Workload, phase: str) -> float:
         n_g = sum(1 for c in cfg.layer_pattern if c == "G")
         frac_g = n_g / len(cfg.layer_pattern)
         ctx = frac_g * ctx + (1 - frac_g) * min(ctx, cfg.sliding_window)
-    per_tok = 2 * cfg.num_kv_heads * cfg.head_dim * w.dtype_bytes
+    per_tok = kv_row_bytes(cfg, w.dtype_bytes)
     extra = (cfg.ssm_d_inner * cfg.ssm_state * 4 * w.batch
              if cfg.has_mamba else 0.0)
     return w.batch * ctx * per_tok + extra
@@ -193,12 +220,11 @@ def memory_terms(cfg: ModelConfig, w: Workload, dtype_bytes: int = 2
     c = cfg.param_counts()
     L = cfg.num_layers
     m_attn = L * c["attn_per_layer"] * dtype_bytes
-    m_exp = L * c["ffn_per_layer"] * dtype_bytes
+    m_exp = c["ffn_total"] * dtype_bytes
     m_embed = (c["embed"] + c["lm_head"]) * dtype_bytes
     total_len = w.prompt + w.gen
     if cfg.has_attention:
-        m_kv = (L * w.batch * total_len * 2 * cfg.num_kv_heads
-                * cfg.head_dim * dtype_bytes)
+        m_kv = L * w.batch * total_len * kv_row_bytes(cfg, dtype_bytes)
     else:
         m_kv = L * w.batch * cfg.ssm_d_inner * (cfg.ssm_state * 4 + 8)
     m_act = w.batch * w.prompt * cfg.d_model * dtype_bytes * 6

@@ -33,9 +33,14 @@ hd)`` pages walked through per-row block tables. A contiguous cache is
 dispatched to the paged Pallas kernel as a one-page-per-row pool behind
 an identity block table, so both layouts share one kernel.
 
+``mla_decode_attention`` is multi-head latent attention's decode entry:
+absorbed queries against one shared latent row a token, over the same
+paged block tables.
+
 ``DISPATCH_COUNTS`` tallies which branch each trace took (keys like
-``decode.pallas_shard_map``); counts tick at trace time, so tests can
-assert a given plan actually routed to the kernel, not the fallback.
+``decode.pallas_shard_map`` or ``mla_decode.pallas``); counts tick at
+trace time, so tests can assert a given plan actually routed to the
+kernel, not the fallback.
 """
 
 from __future__ import annotations
@@ -57,6 +62,7 @@ from . import ref
 from .flash_attention import flash_attention as _flash_pallas
 from .grouped_matmul import grouped_matmul as _gmm_pallas
 from .int4_dequant import int4_dequant as _dequant_pallas
+from .mla_attention import mla_decode_attention as _mla_pallas
 from .paged_attention import (
     paged_attention as _paged_pallas,
     prefix_paged_attention as _prefix_pallas,
@@ -476,6 +482,45 @@ def decode_attention(
         softcap=softcap,
         window=window,
         constrain=constrain,
+    )
+
+
+def mla_decode_attention(
+    q,
+    pool,
+    block_tables,
+    pos,
+    layer,
+    *,
+    kv_rank: int,
+    scale: float,
+    backend: Union[KernelBackend, str, None] = None,
+) -> jax.Array:
+    """Absorbed single-token MLA attention over a latent page pool.
+
+    q: (B, H, W) absorbed queries; pool: (L, N, bs, W) every layer's
+    latent pages, this step's rows already written; ``layer`` the pool
+    layer to read; pos: (B,) query positions. Returns (B, H, kv_rank):
+    ``repro.kernels.mla_attention`` (Pallas) or
+    ``ref.mla_paged_attention_ref``. One latent head serves every q head,
+    so there is no head-sharded variant: MLA runs on unsharded plans.
+    """
+    pos = _normalize_pos(pos)
+    if resolve_backend(backend) is KernelBackend.PALLAS:
+        _record("mla_decode.pallas")
+        return _mla_pallas(
+            q,
+            pool,
+            block_tables,
+            pos,
+            layer,
+            kv_rank=kv_rank,
+            scale=scale,
+            interpret=interpret_mode(),
+        )
+    _record("mla_decode.ref")
+    return ref.mla_paged_attention_ref(
+        q, pool, block_tables, pos, layer, kv_rank=kv_rank, scale=scale
     )
 
 

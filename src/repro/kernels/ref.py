@@ -346,6 +346,35 @@ def append_attention_ref(
     return out, k_cache, v_cache
 
 
+def mla_paged_attention_ref(
+    q: jax.Array,
+    pool: jax.Array,
+    block_tables: jax.Array,
+    pos: jax.Array,
+    layer,
+    *,
+    kv_rank: int,
+    scale: float,
+) -> jax.Array:
+    """Absorbed MLA decode attention (the ``mla_decode_attention`` oracle).
+
+    q: (B, H, W) absorbed queries; pool: (L, N, bs, W) latent pages, this
+    step's rows already written; each row attends the positions
+    ``0 .. pos[b]`` of its logical view (causality is the only validity
+    mask). Scores are q against the whole latent row, values the rows'
+    first ``kv_rank`` columns. Returns (B, H, kv_rank) in q's dtype.
+    """
+    B, max_blocks = block_tables.shape
+    bs = pool.shape[2]
+    rows = pool[layer, block_tables].reshape(B, max_blocks * bs, -1)
+    rows = rows.astype(jnp.float32)
+    s = jnp.einsum("bhw,btw->bht", q.astype(jnp.float32), rows) * scale
+    ok = jnp.arange(max_blocks * bs)[None, None, :] <= pos[:, None, None]
+    p = jax.nn.softmax(jnp.where(ok, s, NEG_INF), axis=-1)
+    out = jnp.einsum("bht,btr->bhr", p, rows[..., :kv_rank])
+    return out.astype(q.dtype)
+
+
 def grouped_matmul_ref(lhs: jax.Array, rhs: jax.Array) -> jax.Array:
     """(E, C, d) x (E, d, f) -> (E, C, f), f32 accumulation."""
     out = jnp.einsum("ecd,edf->ecf", lhs.astype(jnp.float32), rhs.astype(jnp.float32))

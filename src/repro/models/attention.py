@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.kernels import ops as kernel_ops
-from .common import apply_rope, softcap
+from .common import Yarn, apply_rope, rms_norm, softcap, yarn_mscale
 
 NEG_INF = -2.0e38  # f32-safe mask value
 # reserved paged-cache block id — mirrors repro.serving.kv_cache.TRASH_BLOCK
@@ -41,7 +41,18 @@ class AttnTemps(NamedTuple):
 def _scale(cfg: ModelConfig) -> float:
     if cfg.query_pre_attn_scalar > 0:
         return cfg.query_pre_attn_scalar ** -0.5
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        return cfg.head_dim ** -0.5 * yarn_mscale(cfg.yarn_factor,
+                                                  cfg.yarn_mscale_all_dim) ** 2
     return cfg.head_dim ** -0.5
+
+
+def _yarn(cfg: ModelConfig) -> Optional[Yarn]:
+    if not cfg.yarn_factor:
+        return None
+    return Yarn(cfg.yarn_factor, cfg.yarn_original_max_position,
+                cfg.yarn_beta_fast, cfg.yarn_beta_slow, cfg.yarn_mscale,
+                cfg.yarn_mscale_all_dim)
 
 
 def qkv_project(x: jax.Array, w: AttnTemps, cfg: ModelConfig,
@@ -127,10 +138,11 @@ def full_attention(q, k, v, cfg: ModelConfig, is_global,
     ``q_positions`` may be (Sq,) or per-row (B,Sq), and ``kv_len`` a
     scalar or per-row (B,) — see ``_mask_bias``.
     """
-    B, Sq, Hq, hd = q.shape
+    B, Sq, Hq, _ = q.shape
     Sk = k.shape[1]
     Hkv = k.shape[2]
     G = Hq // Hkv
+    hd = v.shape[-1]  # the output's head width: the values'
     if Sk <= kv_chunk:
         bias = _mask_bias(q_positions, k_positions, cfg, is_global, kv_len)
         o, m, s = _sdpa_chunk(q, k, v, bias, cfg)
@@ -140,7 +152,7 @@ def full_attention(q, k, v, cfg: ModelConfig, is_global,
 
     n_chunks = Sk // kv_chunk
     assert Sk % kv_chunk == 0, "kv length must be divisible by kv_chunk"
-    ks = k.reshape(B, n_chunks, kv_chunk, Hkv, hd)
+    ks = k.reshape(B, n_chunks, kv_chunk, Hkv, k.shape[-1])
     vs = v.reshape(B, n_chunks, kv_chunk, Hkv, hd)
     kpos = k_positions.reshape(n_chunks, kv_chunk)
 
@@ -323,3 +335,151 @@ def decode_attention(x: jax.Array, w: AttnTemps, cfg: ModelConfig,
                    w.wo, preferred_element_type=x.dtype)
     return o, k_cache, v_cache
 
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2, arXiv:2405.04434)
+# ---------------------------------------------------------------------------
+class MLATemps(NamedTuple):
+    """Per-layer MLA weights, already unstacked (no leading L).
+
+    wq (d, H*(dn+dr)): per head its dn columns without positions, then
+    its dr roped ones; wkv_a (d, r+dr): the latent c, then the rope key
+    shared by all heads; kv_norm (r,): c's RMS-norm scale; wkv_b
+    (r, H*(dn+dv)): per head the key up-projection W_UK (dn columns),
+    then the value up-projection W_UV (dv); wo (H*dv, d)."""
+    wq: jax.Array
+    wkv_a: jax.Array
+    kv_norm: jax.Array
+    wkv_b: jax.Array
+    wo: jax.Array
+
+
+def mla_project(x: jax.Array, w: MLATemps, cfg: ModelConfig,
+                positions: jax.Array):
+    """x (B, S, d) -> q_nope (B,S,H,dn), q_pe (B,S,H,dr) roped, and the
+    latent cache rows (B, S, latent_width): c RMS-normed, k_pe roped,
+    zeros to the row's width.
+
+    Rope rotates the two halves of each rope block. DeepSeek's own code
+    de-interleaves the (even, odd) columns of q_pe and k_pe before it
+    rotates halves; that is a fixed permutation of wq's and wkv_a's rope
+    columns, which random weights do not see, and the references apply
+    the same convention (``repro.models.reference``)."""
+    B, S, _ = x.shape
+    H, dn, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    r = cfg.kv_lora_rank
+    yarn = _yarn(cfg)
+    q = jnp.einsum("bsd,de->bse", x, w.wq).reshape(B, S, H, dn + dr)
+    q_nope = q[..., :dn]
+    q_pe = apply_rope(q[..., dn:], positions, cfg.rope_theta, yarn)
+    kv = jnp.einsum("bsd,de->bse", x, w.wkv_a)                # (B, S, r+dr)
+    c = rms_norm(kv[..., :r], w.kv_norm, cfg.norm_eps)
+    k_pe = apply_rope(kv[..., None, r:], positions, cfg.rope_theta, yarn)[..., 0, :]
+    pad = jnp.zeros((B, S, cfg.latent_width - r - dr), x.dtype)
+    return q_nope, q_pe, jnp.concatenate([c, k_pe, pad], axis=-1)
+
+
+def _kv_chunk(n: int) -> int:
+    """The key block of the decompressed attention: 512 positions where
+    they divide the context, else all of it in one."""
+    return 512 if n % 512 == 0 else n
+
+
+def mla_attend(q_nope, q_pe, rows, w: MLATemps, cfg: ModelConfig,
+               q_positions, k_positions) -> jax.Array:
+    """Attention in the decompressed head space: every latent row of the
+    context through W_kvb into each head's 128-wide key and value, keys
+    completed by the shared rope key, then causal flash attention.
+    rows (B, T, latent_width) -> (B, Sq, H*dv)."""
+    B, T, _ = rows.shape
+    H, dn, dr, dv = (cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                     cfg.v_head_dim)
+    r = cfg.kv_lora_rank
+    with jax.named_scope("kv_b"):
+        kv = jnp.einsum("btr,re->bte", rows[..., :r], w.wkv_b).reshape(
+            B, T, H, dn + dv)
+    k_pe = jnp.broadcast_to(rows[:, :, None, r:r + dr], (B, T, H, dr))
+    k = jnp.concatenate([kv[..., :dn], k_pe], axis=-1)
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    with jax.named_scope("mla_attend"):
+        out = full_attention(q, k, kv[..., dn:], cfg, True, q_positions,
+                             k_positions, kv_chunk=_kv_chunk(T))
+    return out.reshape(B, q.shape[1], H * dv)
+
+
+def mla_attention_block(x: jax.Array, w: MLATemps, cfg: ModelConfig) -> jax.Array:
+    """Full-sequence MLA (training / forward): (B,S,d) -> (B,S,d)."""
+    B, S, _ = x.shape
+    positions = jnp.arange(S, dtype=jnp.int32)
+    q_nope, q_pe, rows = mla_project(x, w, cfg, positions[None, :])
+    out = mla_attend(q_nope, q_pe, rows, w, cfg, positions, positions)
+    return jnp.einsum("bse,ed->bsd", out.astype(x.dtype), w.wo,
+                      preferred_element_type=x.dtype)
+
+
+def write_latent_rows(pool: jax.Array, layer, rows: jax.Array,
+                      positions: jax.Array, block_tables: jax.Array) -> jax.Array:
+    """Scatter rows (B, C, W) into ``pool[layer]`` (pool (L, N, bs, W)) at
+    each row's ``positions`` (B, C) through its block table; positions past
+    the table width land in the trash block, never in a live page."""
+    bs = pool.shape[2]
+    max_blocks = block_tables.shape[1]
+    blk, off = positions // bs, positions % bs
+    phys = jnp.take_along_axis(block_tables, jnp.clip(blk, 0, max_blocks - 1), axis=1)
+    phys = jnp.where(blk < max_blocks, phys, TRASH_BLOCK)
+    return pool.at[layer, phys, off].set(rows.astype(pool.dtype))
+
+
+def mla_cached_attention(x: jax.Array, w: MLATemps, cfg: ModelConfig,
+                         pool: jax.Array, layer, pos: jax.Array,
+                         block_tables: jax.Array, backend=None):
+    """Cache-appending MLA over the paged latent pool: one decode token or
+    one prefill chunk a row.
+
+    x (B, C, d); pool (L, N, bs, latent_width): every layer's latent pages,
+    ``layer`` the one this call reads and writes; pos (B,) (or a scalar)
+    the rows' first new position; block_tables (B, max_blocks). The C new
+    latent rows are written first, so each query attends its own token.
+
+    C == 1 (decode) attends in the latent space: W_UK folds into each
+    head's query (``mla_absorb``), the kernel seam
+    (``ops.mla_decode_attention``: Pallas or its jnp oracle) scores the
+    queries against the whole latent rows and sums their first r columns
+    (``mla_attend``), and W_UV follows. C > 1 (a prefill chunk)
+    decompresses the row's whole table through W_kvb and attends in the
+    head space (``mla_attend``, XLA): for 512 queries that is about half
+    the operations of the latent-space product. Returns (out (B, C, d),
+    pool)."""
+    B, C = x.shape[0], x.shape[1]
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    q_pos = pos[:, None] + jnp.arange(C, dtype=jnp.int32)       # (B, C)
+    q_nope, q_pe, rows = mla_project(x, w, cfg, q_pos)
+    with jax.named_scope("kv_write"):
+        pool = write_latent_rows(pool, layer, rows, q_pos, block_tables)
+    H, dn, dv = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+    r = cfg.kv_lora_rank
+    if C == 1:
+        wkv_b = w.wkv_b.reshape(r, H, dn + dv)
+        with jax.named_scope("mla_absorb"):
+            q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], wkv_b[..., :dn],
+                               preferred_element_type=jnp.float32)
+            pad = jnp.zeros((B, H, cfg.latent_width - r - q_pe.shape[-1]), pool.dtype)
+            q_abs = jnp.concatenate(
+                [q_lat.astype(pool.dtype), q_pe[:, 0].astype(pool.dtype), pad], -1)
+        with jax.named_scope("mla_attend"):
+            o_lat = kernel_ops.mla_decode_attention(
+                q_abs, pool, block_tables, pos, layer, kv_rank=r, scale=_scale(cfg),
+                backend=backend)
+        with jax.named_scope("mla_absorb"):
+            out = jnp.einsum("bhr,rhv->bhv", o_lat.astype(x.dtype), wkv_b[..., dn:],
+                             preferred_element_type=x.dtype).reshape(B, 1, H * dv)
+    else:
+        kernel_ops.DISPATCH_COUNTS["mla_chunk.xla"] += 1
+        bs = pool.shape[2]
+        T = block_tables.shape[1] * bs
+        ctx = pool[layer, block_tables]  # one gather: no copy of the layer
+        out = mla_attend(q_nope, q_pe, ctx.reshape(B, T, -1), w, cfg, q_pos,
+                         jnp.arange(T, dtype=jnp.int32))
+    o = jnp.einsum("bse,ed->bsd", out.astype(x.dtype), w.wo,
+                   preferred_element_type=x.dtype)
+    return o, pool

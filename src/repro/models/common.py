@@ -1,6 +1,9 @@
 """Shared numerical building blocks for the model zoo."""
 from __future__ import annotations
 
+import math
+from typing import NamedTuple, Optional, Tuple
+
 import jax
 import jax.numpy as jnp
 
@@ -34,14 +37,58 @@ def softcap(x: jax.Array, cap: float) -> jax.Array:
     return (cap * jnp.tanh(x.astype(jnp.float32) / cap)).astype(x.dtype)
 
 
-def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
-    """Inverse frequencies for rotary embeddings, shape (head_dim // 2,)."""
+class Yarn(NamedTuple):
+    """YaRN rotary scaling (arXiv:2309.00071), as DeepSeek-V2 configures
+    it (``rope_scaling`` of type ``yarn``)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float
+    beta_slow: float
+    mscale: float
+    mscale_all_dim: float
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude factor ``0.1 * mscale * ln(factor) + 1`` (1 where
+    the factor does not stretch)."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(dim: int, theta: float, yarn: Yarn) -> Tuple[int, int]:
+    """The rotary pair indices between which YaRN ramps from the original
+    inverse frequency (below ``low``: ``beta_fast`` rotations or more over
+    the original context) to the interpolated one (above ``high``: fewer
+    than ``beta_slow``)."""
+    def index(rotations: float) -> float:
+        return (dim * math.log(yarn.original_max_position / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(index(yarn.beta_fast)), 0)
+    high = min(math.ceil(index(yarn.beta_slow)), dim - 1)
+    return low, high
+
+
+def rope_frequencies(head_dim: int, theta: float,
+                     yarn: Optional[Yarn] = None) -> jax.Array:
+    """Inverse frequencies for rotary embeddings, shape (head_dim // 2,).
+    With ``yarn``: divided by its factor above the correction range, as
+    they are below it, linear in the pair index between."""
     exponent = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
-    return 1.0 / (theta ** exponent)
+    inv = 1.0 / (theta ** exponent)
+    if yarn is None:
+        return inv
+    low, high = yarn_correction_range(head_dim, theta, yarn)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return inv / yarn.factor * ramp + inv * (1.0 - ramp)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary position embedding.
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn: Optional[Yarn] = None) -> jax.Array:
+    """Rotary position embedding: the two halves of D rotate by position
+    angles (``yarn`` rescales the frequencies and cos/sin).
 
     x:         (..., S, H, D)
     positions: (..., S) int32 - broadcastable against x's batch/seq dims.
@@ -49,11 +96,16 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     if theta <= 0.0:
         return x
     d = x.shape[-1]
-    inv_freq = rope_frequencies(d, theta)                       # (D/2,)
+    inv_freq = rope_frequencies(d, theta, yarn)                 # (D/2,)
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # (..., S, D/2)
     # insert head axis: (..., S, 1, D/2)
     angles = angles[..., None, :]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -3,7 +3,11 @@
 Parameters are stored as a nested dict pytree with every per-layer leaf
 STACKED along a leading ``num_layers`` axis so the forward pass can
 ``lax.scan`` over layers — this keeps the lowered HLO one-layer-sized,
-which is what makes 512-device dry-run compiles tractable.
+which is what makes 512-device dry-run compiles tractable. Leading dense
+layers of an MoE model (``cfg.first_k_dense_replace``) run one at a time
+before the scan and are not stacked: ``dense_layers["0"]``, ``["1"]``, ...
+each hold one layer's attention, norms and dense FFN of ``cfg.d_ff``; the
+``layers`` stack then holds the MoE layers after them.
 """
 from __future__ import annotations
 
@@ -23,9 +27,36 @@ def _dtype(cfg: ModelConfig, override: Optional[str] = None):
     return jnp.dtype(override or cfg.dtype)
 
 
+def _attn_shapes(cfg: ModelConfig, lead: tuple) -> Dict[str, Any]:
+    """Attention leaves with leading dims ``lead`` ((L,) stacked, () one)."""
+    d, hq = cfg.d_model, cfg.num_heads
+    if cfg.is_mla:
+        r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        return {
+            "wq": lead + (d, hq * cfg.head_dim),
+            "wkv_a": lead + (d, r + dr),
+            "kv_norm": lead + (r,),
+            "wkv_b": lead + (r, hq * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            "wo": lead + (hq * cfg.v_head_dim, d),
+        }
+    hkv, hd = cfg.num_kv_heads, cfg.head_dim
+    return {
+        "wq": lead + (d, hq * hd),
+        "wk": lead + (d, hkv * hd),
+        "wv": lead + (d, hkv * hd),
+        "wo": lead + (hq * hd, d),
+    }
+
+
+def _glu_shapes(d: int, f: int, lead: tuple) -> Dict[str, Any]:
+    return {"wi_gate": lead + (d, f), "wi_up": lead + (d, f), "wo": lead + (f, d)}
+
+
 def param_shapes(cfg: ModelConfig) -> Dict[str, Any]:
     """Abstract shapes (tuples) of every parameter leaf."""
-    L, d, V = cfg.num_layers, cfg.d_model, cfg.vocab_size
+    d, V = cfg.d_model, cfg.vocab_size
+    K = cfg.num_dense_layers
+    L = cfg.num_layers - K
     shapes: Dict[str, Any] = {"embed": (V, d), "final_norm": (d,)}
     if not cfg.tie_embeddings:
         shapes["lm_head"] = (d, V)
@@ -37,13 +68,7 @@ def param_shapes(cfg: ModelConfig) -> Dict[str, Any]:
         shapes_post = {"ln1_post": (L, d), "ln2_post": (L, d)}
         layers.update(shapes_post)
     if cfg.has_attention:
-        hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        layers["attn"] = {
-            "wq": (L, d, hq * hd),
-            "wk": (L, d, hkv * hd),
-            "wv": (L, d, hkv * hd),
-            "wo": (L, hq * hd, d),
-        }
+        layers["attn"] = _attn_shapes(cfg, (L,))
     if cfg.has_mamba:
         di, n, r, cw = cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_dt_rank, cfg.ssm_conv
         layers["mamba"] = {
@@ -65,8 +90,7 @@ def param_shapes(cfg: ModelConfig) -> Dict[str, Any]:
         glu = cfg.activation in ("silu", "gelu")
         f = cfg.d_ff
         if glu:
-            layers["ffn"] = {"wi_gate": (L, d, f), "wi_up": (L, d, f),
-                             "wo": (L, f, d)}
+            layers["ffn"] = _glu_shapes(d, f, (L,))
         else:
             layers["ffn"] = {"wi": (L, d, f), "wo": (L, f, d)}
     elif cfg.ffn_type == "moe":
@@ -85,6 +109,11 @@ def param_shapes(cfg: ModelConfig) -> Dict[str, Any]:
             moe["shared_wo"] = (L, sf * s, d)
         layers["moe"] = moe
     shapes["layers"] = layers
+    if K:
+        shapes["dense_layers"] = {
+            str(i): {"ln1": (d,), "ln2": (d,), "attn": _attn_shapes(cfg, ()),
+                     "ffn": _glu_shapes(d, cfg.d_ff, ())}
+            for i in range(K)}
     return shapes
 
 
@@ -159,7 +188,12 @@ def param_pspecs(cfg: ModelConfig, plan) -> Dict[str, Any]:
     if cfg.use_post_norm:
         layers["ln1_post"] = P(None, None)
         layers["ln2_post"] = P(None, None)
-    if cfg.has_attention:
+    if cfg.is_mla:
+        # one latent head serves every q head: nothing of it splits
+        attn = {n: P(None, None, None) for n in ("wq", "wkv_a", "wkv_b", "wo")}
+        attn["kv_norm"] = P(None, None)
+        layers["attn"] = attn
+    elif cfg.has_attention:
         layers["attn"] = {
             "wq": P(None, None, at),
             "wk": P(None, None, at if kv_ok else None),
@@ -214,6 +248,15 @@ def param_pspecs(cfg: ModelConfig, plan) -> Dict[str, Any]:
             moe["shared_wo"] = P(None, tp, None)
         layers["moe"] = moe
     specs["layers"] = layers
+    if cfg.num_dense_layers:
+        # one layer's leaves: the stacked specs without their layer dim
+        one = jax.tree.map(lambda p: P(*tuple(p)[1:]), layers["attn"],
+                           is_leaf=lambda x: isinstance(x, P))
+        specs["dense_layers"] = {
+            str(i): {"ln1": P(None), "ln2": P(None), "attn": one,
+                     "ffn": {"wi_gate": P(None, tp), "wi_up": P(None, tp),
+                             "wo": P(tp, None)}}
+            for i in range(cfg.num_dense_layers)}
     return _sanitize(param_shapes(cfg), specs, plan)
 
 

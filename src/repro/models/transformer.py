@@ -6,6 +6,14 @@ VLM-audio frontends) are selected by ``ModelConfig`` flags; per-layer
 local-vs-global attention comes in as a traced bool array so weight shapes
 stay uniform.
 
+Leading dense layers (``cfg.first_k_dense_replace``, DeepSeek's dense
+first layer) run before the scan, one at a time, each from its own
+unstacked weights (``params["dense_layers"]["0"]``, ...); the scan covers
+the MoE layers that follow.
+Multi-head latent attention (``cfg.is_mla``) keeps one latent row a token
+in a paged pool that every layer's pages share (``init_paged_cache``),
+carried through the layer loop and written in place.
+
 Public entry points:
   loss_and_aux   — training loss (LM CE + MoE aux)
   prefill        — full forward returning last-position logits + decode cache
@@ -131,7 +139,11 @@ def _mixer_full(x, lp, flag, cfg: ModelConfig, plan, collect_kv: bool,
     """Attention / mamba / hybrid sublayer. Returns (mixed, (k, v) or None)."""
     kv = None
     h = _sp_gather(rms_norm(x, lp["ln1"], cfg.norm_eps), plan)
-    if cfg.block_type == "attention":
+    if cfg.is_mla:
+        if collect_kv:
+            raise NotImplementedError(_MLA_PAGED_ONLY)
+        out = attn_mod.mla_attention_block(h, attn_mod.MLATemps(**lp["attn"]), cfg)
+    elif cfg.block_type == "attention":
         w = attn_mod.AttnTemps(**lp["attn"])
         if collect_kv:
             out, kv = attn_mod.attention_block(h, w, cfg, flag, plan,
@@ -185,6 +197,38 @@ def _ffn_full(x, lp, cfg: ModelConfig, plan, backend=None):
     return out, aux, route_idx
 
 
+def _dense_ffn(x, lp, cfg: ModelConfig, plan):
+    """A leading dense layer's FFN sublayer (width ``cfg.d_ff``)."""
+    with jax.named_scope("dense_ffn"):
+        h = _sp_gather(rms_norm(x, lp["ln2"], cfg.norm_eps), plan)
+        f = lp["ffn"]
+        return glu_ffn(h, f["wi_gate"], f["wi_up"], f["wo"], cfg.activation)
+
+
+def _dense_layer(params, i: int):
+    """The weights of leading dense layer ``i``."""
+    return params["dense_layers"][str(i)]
+
+
+def dense_layers_full(x, params, cfg: ModelConfig, plan, collect_kv: bool = False,
+                      backend=None):
+    """The leading dense layers over a full sequence, one at a time.
+    Returns (x, [(k, v) per layer] when ``collect_kv``)."""
+    flags = cfg.global_layer_flags()
+    kvs = []
+    for i in range(cfg.num_dense_layers):
+        lp = _dense_layer(params, i)
+        with jax.named_scope("attn"):
+            mixed, kv = _mixer_full(x, lp, jnp.asarray(flags[i]), cfg, plan,
+                                    collect_kv, backend)
+        x = x + mixed
+        x = x + _dense_ffn(x, lp, cfg, plan)
+        if plan is not None and not plan.is_null:
+            x = plan.constrain(x, plan.act_btd())
+        kvs.append(kv)
+    return x, kvs
+
+
 def layer_full(x, lp, flag, cfg: ModelConfig, plan, collect_kv: bool = False,
                backend=None):
     with jax.named_scope("attn"):
@@ -203,7 +247,15 @@ def layer_full(x, lp, flag, cfg: ModelConfig, plan, collect_kv: bool = False,
 # full forward (train / prefill)
 # ---------------------------------------------------------------------------
 def _layer_flags(cfg: ModelConfig) -> jax.Array:
-    return jnp.asarray(cfg.global_layer_flags(), dtype=bool)
+    """Local/global flags of the scanned layers (those after the leading
+    dense ones)."""
+    flags = cfg.global_layer_flags()[cfg.num_dense_layers:]
+    return jnp.asarray(flags, dtype=bool)
+
+
+_MLA_PAGED_ONLY = ("multi-head latent attention keeps its latent cache in the "
+                   "paged pool: serve it through the continuous engine (paged "
+                   "KV, chunked prefill)")
 
 
 def forward_hidden(params, cfg: ModelConfig, x: jax.Array, plan,
@@ -217,6 +269,7 @@ def forward_hidden(params, cfg: ModelConfig, x: jax.Array, plan,
     threads the engine's backend instead.
     """
     flags = _layer_flags(cfg)
+    x, dense_kv = dense_layers_full(x, params, cfg, plan, collect_kv, backend)
 
     def body(carry, per_layer):
         h, aux_acc = carry
@@ -233,7 +286,16 @@ def forward_hidden(params, cfg: ModelConfig, x: jax.Array, plan,
         body_fn = body
     (h, aux), kvs = _scan(body_fn, (x, jnp.zeros((), jnp.float32)),
                           (params["layers"], flags))
+    if collect_kv and dense_kv:
+        kvs = _stack_front(dense_kv, kvs)
     return h, kvs, aux
+
+
+def _stack_front(front, stacked):
+    """Per-layer (k, v) pairs of the leading layers stacked in front of the
+    scanned layers' stacked pair."""
+    return tuple(jnp.concatenate([jnp.stack([kv[i] for kv in front]), stacked[i]])
+                 for i in range(2))
 
 
 def loss_and_aux(params, cfg: ModelConfig, batch: Dict[str, jax.Array],
@@ -260,6 +322,8 @@ def loss_and_aux(params, cfg: ModelConfig, batch: Dict[str, jax.Array],
 # ---------------------------------------------------------------------------
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                dtype=jnp.bfloat16, plan=None) -> DecodeCache:
+    if cfg.is_mla:
+        raise NotImplementedError(_MLA_PAGED_ONLY)
     L = cfg.num_layers
     k = v = conv = ssm = None
     if cfg.has_attention:
@@ -292,11 +356,21 @@ def init_paged_cache(cfg: ModelConfig, nslots: int, num_blocks: int,
     ``repro.serving.kv_cache``). Mamba state is not paged — attention-only
     models for now; the serving engine falls back to contiguous slots for
     mamba/hybrid families.
+
+    Multi-head latent attention pages one latent row a token and layer,
+    key and value at once: ``k`` is ``(L, num_blocks, block_size,
+    cfg.latent_width)`` and ``v`` is None.
     """
     assert cfg.has_attention and not cfg.has_mamba, \
         "paged caches cover attention KV only (mamba state is unpaged)"
     L = cfg.num_layers
     kv_dt = jnp.dtype(cfg.kv_cache_dtype) if cfg.kv_cache_dtype else dtype
+    tables = jnp.zeros((nslots, max_blocks), jnp.int32)
+    if cfg.is_mla:
+        return DecodeCache(
+            k=jnp.zeros((L, num_blocks, block_size, cfg.latent_width), kv_dt),
+            v=None, conv=None, ssm=None,
+            pos=jnp.zeros((nslots,), jnp.int32), block_tables=tables)
     shape = (L, num_blocks, block_size, cfg.num_kv_heads, cfg.head_dim)
     k = jnp.zeros(shape, kv_dt)
     v = jnp.zeros(shape, kv_dt)
@@ -305,8 +379,7 @@ def init_paged_cache(cfg: ModelConfig, nslots: int, num_blocks: int,
         v = plan.constrain(v, plan.kv_cache_spec())
     return DecodeCache(
         k=k, v=v, conv=None, ssm=None,
-        pos=jnp.zeros((nslots,), jnp.int32),
-        block_tables=jnp.zeros((nslots, max_blocks), jnp.int32))
+        pos=jnp.zeros((nslots,), jnp.int32), block_tables=tables)
 
 
 def prefill(params, cfg: ModelConfig, batch: Dict[str, jax.Array],
@@ -324,13 +397,19 @@ def prefill(params, cfg: ModelConfig, batch: Dict[str, jax.Array],
     (DESIGN.md §Kernel backends).
     """
     assert cfg.causal, "prefill/decode only for decoder models"
+    if cfg.is_mla:
+        raise NotImplementedError(_MLA_PAGED_ONLY)
     x = embed_inputs(params, cfg, batch, plan)
     B, S = x.shape[0], x.shape[1]
 
     flags = _layer_flags(cfg)
+    x, dense_kv = dense_layers_full(x, params, cfg, plan, cfg.has_attention,
+                                    backend)
     body = make_prefill_body(cfg, plan, backend)
     (h, _aux), ys = _scan(
         body, (x, jnp.zeros((), jnp.float32)), (params["layers"], flags))
+    if dense_kv:
+        ys["kv"] = _stack_front(dense_kv, ys["kv"])
     return _prefill_finish(params, cfg, h, ys, B, S, max_len, plan)
 
 
@@ -527,30 +606,120 @@ def decode_step(params, cfg: ModelConfig, token: jax.Array,
     if plan is not None and not plan.is_null:
         x = plan.constrain(x, plan.act_btd())
     pos = cache.pos
+    collect_routing = collect_routing and cfg.ffn_type == "moe"
+    if cfg.is_mla:
+        return _mla_decode_step(params, cfg, x, cache, plan, backend,
+                                collect_routing)
     flags = _layer_flags(cfg)
+    kv = (cache.k, cache.v)
+    x, dense_kv = _dense_decode_prefix(params, cfg, x, cache, plan, backend)
+    if dense_kv:
+        K = cfg.num_dense_layers
+        kv = (cache.k[K:], cache.v[K:])
 
-    layers, stacked = split_decode_stacks(cfg, params["layers"],
-                                          (cache.k, cache.v))
+    layers, stacked = split_decode_stacks(cfg, params["layers"], kv)
     xs: Dict[str, Any] = {"lp": layers, "flag": flags,
                           "layer": jnp.arange(flags.shape[0])}
     if cfg.has_mamba:
         xs["conv"] = cache.conv
         xs["ssm"] = cache.ssm
 
-    collect_routing = collect_routing and cfg.ffn_type == "moe"
     body = make_decode_body(cfg, plan, pos, stacked, cache.block_tables,
                             backend, prefix_groups=cache.prefix_groups,
                             collect_routing=collect_routing)
     h, ys = _scan(body, x, xs)
     new_cache = cache._replace(pos=pos + C, route_topk=None)
     if cfg.has_attention:
-        new_cache = new_cache._replace(k=ys["k"], v=ys["v"])
+        k, v = ys["k"], ys["v"]
+        if dense_kv:
+            k, v = _stack_front(dense_kv, (k, v))
+        new_cache = new_cache._replace(k=k, v=v)
     if cfg.has_mamba:
         new_cache = new_cache._replace(conv=ys["conv"], ssm=ys["ssm"])
     if collect_routing:
         new_cache = new_cache._replace(route_topk=ys["route"])
     logits = unembed(params, cfg, h[:, -1:, :])
     return logits[:, 0], new_cache
+
+
+def _dense_decode_prefix(params, cfg: ModelConfig, x, cache: DecodeCache, plan,
+                         backend):
+    """The leading dense layers of a (GQA) decode step, one at a time, each
+    on its own layer of the cache. Returns (x, [(k, v) per layer])."""
+    out = []
+    flags = cfg.global_layer_flags()
+    for i in range(cfg.num_dense_layers):
+        lp = _dense_layer(params, i)
+        hn = rms_norm(x, lp["ln1"], cfg.norm_eps)
+        with jax.named_scope("attn"):
+            a, k_c, v_c = attn_mod.decode_attention(
+                hn, attn_mod.AttnTemps(**lp["attn"]), cfg, jnp.asarray(flags[i]),
+                cache.k[i], cache.v[i], cache.pos, plan,
+                block_tables=cache.block_tables, prefix_groups=cache.prefix_groups,
+                backend=backend)
+        x = x + a
+        x = x + _dense_ffn(x, lp, cfg, plan)
+        out.append((k_c, v_c))
+    return x, out
+
+
+def _mla_decode_step(params, cfg: ModelConfig, x, cache: DecodeCache, plan,
+                     backend, collect_routing: bool):
+    """``decode_step`` for multi-head latent attention over the paged
+    latent pool: the leading dense layers, then the MoE scan, each layer
+    writing its new rows into and attending its own layer of the one pool,
+    which the loop carries (no per-layer copy of it out and back)."""
+    if cache.block_tables is None:
+        raise NotImplementedError(_MLA_PAGED_ONLY)
+    if plan is not None and not plan.is_null:
+        raise NotImplementedError(
+            "multi-head latent attention runs on unsharded plans: its one "
+            "latent head does not split over a mesh axis")
+    C = x.shape[1]
+    pos, tables = cache.pos, cache.block_tables
+    K = cfg.num_dense_layers
+
+    def attend(h, lp, layer, pool):
+        hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
+        with jax.named_scope("attn"):
+            a, pool = attn_mod.mla_cached_attention(
+                hn, attn_mod.MLATemps(**lp["attn"]), cfg, pool, layer, pos, tables,
+                backend=backend)
+        return h + a, pool
+
+    h, pool = x, cache.k
+    for i in range(K):
+        lp = _dense_layer(params, i)
+        h, pool = attend(h, lp, i, pool)
+        h = h + _dense_ffn(h, lp, cfg, plan)
+
+    layers, stacked = split_decode_stacks(cfg, params["layers"], None)
+
+    def body(carry, per_layer):
+        h, pool = carry
+        lp = per_layer["lp"]
+        if "experts" in stacked:
+            with jax.named_scope("experts"):
+                lp = {**lp, "moe": {**lp["moe"],
+                                    **_take(stacked["experts"], per_layer["layer"])}}
+        h, pool = attend(h, lp, per_layer["layer"] + K, pool)
+        ffn_out, _aux, route_idx = _ffn_full(h, lp, cfg, plan, backend)
+        ys = {"route": route_idx} if collect_routing and route_idx is not None else {}
+        return (h + ffn_out, pool), ys
+
+    n = cfg.num_layers - K
+    (h, pool), ys = _scan(body, (h, pool), {"lp": layers, "layer": jnp.arange(n)})
+    new_cache = cache._replace(k=pool, pos=pos + C, route_topk=ys.get("route"))
+    logits = unembed(params, cfg, h[:, -1:, :])
+    return logits[:, 0], new_cache
+
+
+def _take(tree, layer):
+    """Layer ``layer`` (traced) of every leaf of a stacked subtree."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(
+            a, layer, keepdims=False, allow_negative_indices=False),
+        tree)
 
 
 def split_decode_stacks(cfg: ModelConfig, layers, kv):
@@ -567,7 +736,7 @@ def split_decode_stacks(cfg: ModelConfig, layers, kv):
         moe = dict(layers["moe"])
         stacked["experts"] = {n: moe.pop(n) for n in moe_mod.EXPERT_LEAVES}
         layers = {**layers, "moe": moe}
-    if cfg.has_attention:
+    if cfg.has_attention and kv is not None:
         stacked["kv"] = tuple(kv)
     return layers, stacked
 
@@ -590,22 +759,16 @@ def make_decode_body(cfg: ModelConfig, plan, pos, stacked, block_tables=None,
     K and V pools).
     """
 
-    def take(tree, layer):
-        return jax.tree.map(
-            lambda a: jax.lax.dynamic_index_in_dim(
-                a, layer, keepdims=False, allow_negative_indices=False),
-            tree)
-
     def body(h, per_layer):
         lp, flag = per_layer["lp"], per_layer["flag"]
         if "experts" in stacked:
             with jax.named_scope("experts"):
                 lp = {**lp, "moe": {**lp["moe"],
-                                    **take(stacked["experts"],
+                                    **_take(stacked["experts"],
                                            per_layer["layer"])}}
         if "kv" in stacked:
             with jax.named_scope("kv_write"):
-                k_l, v_l = take(stacked["kv"], per_layer["layer"])
+                k_l, v_l = _take(stacked["kv"], per_layer["layer"])
         ys: Dict[str, Any] = {}
         hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
         outs = []

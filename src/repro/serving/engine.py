@@ -306,6 +306,9 @@ class InferenceEngine:
         self.paged = can_page if paged is None else paged
         if self.paged and not can_page:
             raise ValueError("paged KV serving requires an attention-only model")
+        if cfg.is_mla and not self.paged:
+            raise ValueError("multi-head latent attention serves from the paged "
+                             "latent pool only")
         if kv_block_size < 1:
             raise ValueError("kv_block_size must be positive")
         self.kv_block_size = kv_block_size
@@ -366,14 +369,15 @@ class InferenceEngine:
         if self.prefetch and not cfg.is_moe:
             raise ValueError("prefetch requires an MoE config")
         self.prefetch_top_p = float(prefetch_top_p)
+        moe_layers = cfg.num_layers - cfg.num_dense_layers
         self._tracker: Optional[RoutingTracker] = (
-            RoutingTracker(cfg.num_layers, cfg.n_routed_experts, ema=routing_ema)
+            RoutingTracker(moe_layers, cfg.n_routed_experts, ema=routing_ema)
             if self.replicate_experts or self.prefetch
             else None
         )
         self._predictor: Optional[NextLayerPredictor] = (
             NextLayerPredictor(
-                cfg.num_layers, cfg.n_routed_experts, top_p=self.prefetch_top_p
+                moe_layers, cfg.n_routed_experts, top_p=self.prefetch_top_p
             )
             if self.prefetch
             else None
@@ -423,6 +427,13 @@ class InferenceEngine:
             self._make_experts_resident()
 
     # -- jit function cache ----------------------------------------------
+    def _donate_cache(self, argnum: int) -> tuple:
+        """The cache argument to donate to a step program: a latent pool
+        (multi-head latent attention) is carried through the layers and
+        written in place, so donating it spares the step a copy of the
+        whole pool; the other families keep their buffers."""
+        return (argnum,) if self.cfg.is_mla else ()
+
     def _jit(self, key, build):
         """One jitted wrapper per (kind, plan) — jax.jit's own cache then
         retraces per argument shape, so a previously-seen shape class
@@ -453,7 +464,9 @@ class InferenceEngine:
                 p, cfg, t, c, plan=plan, backend=be, collect_routing=collect
             )
 
-        return self._jit(("decode", plan), lambda: jax.jit(decode))
+        return self._jit(
+            ("decode", plan),
+            lambda: jax.jit(decode, donate_argnums=self._donate_cache(2)))
 
     def _chunk_fn(self, plan):
         """Append one B=1 prefill chunk through a row's block table."""
@@ -462,7 +475,9 @@ class InferenceEngine:
         def chunk(p, t, row, c):
             return _chunk_append(p, cfg, t, row, c, plan, be)
 
-        return self._jit(("chunk", plan), lambda: jax.jit(chunk))
+        return self._jit(
+            ("chunk", plan),
+            lambda: jax.jit(chunk, donate_argnums=self._donate_cache(3)))
 
     def _cow_fn(self):
         """Copy-on-write fork: duplicate pool pages ``src`` into ``dst``
@@ -470,7 +485,9 @@ class InferenceEngine:
         DESIGN.md §4d)."""
 
         def cow(k, v, src, dst):
-            return k.at[:, dst].set(k[:, src]), v.at[:, dst].set(v[:, src])
+            # a latent pool (multi-head latent attention) has no separate v
+            return k.at[:, dst].set(k[:, src]), (
+                None if v is None else v.at[:, dst].set(v[:, src]))
 
         return self._jit(("cow",), lambda: jax.jit(cow))
 
@@ -490,7 +507,9 @@ class InferenceEngine:
                 p, cfg, dec_tok, cache, plan=plan, backend=be, collect_routing=collect
             )
 
-        return self._jit(("fused", plan), lambda: jax.jit(fused))
+        return self._jit(
+            ("fused", plan),
+            lambda: jax.jit(fused, donate_argnums=self._donate_cache(4)))
 
     def _sharding_for(self, phase: str):
         """Execution layout for a phase under the active plan, with the

@@ -5,7 +5,7 @@ from repro.configs import ASSIGNED_ARCHS, PAPER_ARCHS, get_config, list_configs
 
 
 def test_all_configs_load():
-    assert len(list_configs()) == 13
+    assert len(list_configs()) == 14
     for name in ASSIGNED_ARCHS + PAPER_ARCHS:
         cfg = get_config(name)
         assert cfg.num_layers > 0 and cfg.d_model > 0

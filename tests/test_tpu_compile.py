@@ -13,6 +13,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.grouped_matmul import grouped_matmul
 from repro.kernels.int4_dequant import int4_dequant
+from repro.kernels.mla_attention import mla_decode_attention
 from repro.kernels.paged_attention import paged_attention, prefix_paged_attention
 
 H, HD, BS = 16, 128, 16  # q = kv heads, head_dim, KV page tokens
@@ -86,6 +87,18 @@ def _dequant():
                 ((groups, 1), jnp.float32)]
 
 
+def _mla():
+    """DeepSeek-V2-Lite's absorbed decode: 32 rows over 800-page tables
+    (12,800 positions), a 5-layer latent pool of 25,601 pages, 16 heads,
+    512 + 64 latent values a row held 640 lanes wide."""
+    def fn(q, pool, tables, pos, layer):
+        return mla_decode_attention(q, pool, tables, pos, layer, kv_rank=512,
+                                    scale=0.114721, interpret=False)
+
+    return fn, [((32, H, 640), jnp.bfloat16), ((5, 25601, BS, 640), jnp.bfloat16),
+                ((32, 800), jnp.int32), ((32,), jnp.int32), ((), jnp.int32)]
+
+
 CASES = {
     "paged_decode": lambda: _paged(SLOTS, 1),
     "paged_chunk": lambda: _paged(1, CHUNK),
@@ -99,6 +112,7 @@ CASES = {
     "gmm_c8": lambda: _gmm(8),
     "gmm_c200": lambda: _gmm(200),
     "int4_dequant_expert": _dequant,
+    "mla_decode_v2lite": _mla,
 }
 
 
